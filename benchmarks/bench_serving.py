@@ -62,7 +62,9 @@ _PAGE = 8
 def _backend_label() -> str:
     if not ops.enabled():
         return "xla"
-    return "pallas_interp" if ops._STATE["interpret"] else "pallas"
+    from repro.kernels.backend import resolve_interpret
+    return ("pallas_interp" if resolve_interpret(ops._STATE["interpret"])
+            else "pallas")
 
 
 def _workload(cfg, n_requests: int, seed: int = 0):

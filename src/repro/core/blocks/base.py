@@ -40,6 +40,9 @@ class CurvatureBlock(abc.ABC):
     def __init__(self, meta: LayerMeta, cfg):
         self.meta = meta
         self.cfg = cfg
+        # op -> "pallas" | "einsum": the route each op took when it was last
+        # traced (read by ``route_counts``; kernels can decline on shape)
+        self.routes: Dict[str, str] = {}
 
     @classmethod
     def handles(cls, meta: LayerMeta) -> bool:
@@ -59,7 +62,11 @@ class CurvatureBlock(abc.ABC):
 
     @staticmethod
     def _interpret() -> bool:
-        return jax.default_backend() != "tpu"
+        from repro.kernels.backend import resolve_interpret
+        return resolve_interpret(None)
+
+    def _route(self, op: str, kernel: bool) -> None:
+        self.routes[op] = "pallas" if kernel else "einsum"
 
     def _tuned(self, kernel: str, shape, dtype) -> dict:
         """Autotuned tile kwargs for ``kernel`` on this problem, or ``{}``
@@ -140,6 +147,8 @@ class CurvatureBlock(abc.ABC):
 
     def update_factors(self, old, rec, gprobe, batch, n: int, eps):
         """Decayed blend ``C ← ε C + (1−ε) contrib``; ε may be traced."""
+        self._route("factor_update.a", False)
+        self._route("factor_update.g", False)
         return F.blend(old, self.stats_contrib(rec, gprobe, batch, n), eps)
 
     # ------------------------------------------------------------------
@@ -155,6 +164,7 @@ class CurvatureBlock(abc.ABC):
     # ------------------------------------------------------------------
     def precondition(self, inv, v):
         """``U = Ā⁻¹ V G⁻¹`` with this block's structure; v shaped like W."""
+        self._route("precond", False)
         return INV.apply_block_inverse(self.meta, inv, v)
 
     def precond_momentum(self, inv, v, mom, alpha, mu, eigen: bool = False):
@@ -162,6 +172,7 @@ class CurvatureBlock(abc.ABC):
         ``D = alpha·precondition(v) + mu·mom`` plus ``Σ D²`` — the squared
         norm comes out of the same pass so the global-norm clip never
         re-reads the update.  Subclasses may serve this with one kernel."""
+        self._route("update_chain", False)
         u = (self.precondition_eigen(inv, v) if eigen
              else self.precondition(inv, v))
         d = alpha * u.astype(jnp.float32) + mu * mom
@@ -201,6 +212,7 @@ class CurvatureBlock(abc.ABC):
 
     def precondition_eigen(self, eig, v):
         """``U = Q_A [ (Q_Aᵀ V Q_G) / (s + damp) ] Q_Gᵀ``; v shaped like W."""
+        self._route("rotate_rescale", False)
         return INV.apply_eigen(self.meta, eig, v)
 
     def ihvp(self, eig, v):
@@ -268,3 +280,15 @@ def resolve(meta: LayerMeta) -> Type[CurvatureBlock]:
 def build_blocks(metas: Dict[str, LayerMeta], cfg) -> Dict[str, CurvatureBlock]:
     """One resolved block instance per tagged layer."""
     return {name: resolve(m)(m, cfg) for name, m in metas.items()}
+
+
+def route_counts(blocks) -> Dict[str, Dict[str, int]]:
+    """``{op: {"pallas": n, "einsum": m}}`` over the blocks that traced
+    ``op`` — how many took the Pallas kernel and how many declined to the
+    einsum path (ragged widths, stacked records, ``kernel_backend="xla"``)."""
+    out: Dict[str, Dict[str, int]] = {}
+    for blk in blocks.values():
+        for op, route in blk.routes.items():
+            c = out.setdefault(op, {"pallas": 0, "einsum": 0})
+            c[route] += 1
+    return dict(sorted(out.items()))

@@ -88,4 +88,5 @@ class ConvKronecker(DenseKronecker):
                                           eps)
         # A fused; G identically to the dense route — cotangents of the
         # (1/N)-normalized sampled loss over every spatial location
+        self._route("factor_update.a", True)
         return {"a": a_new, "g": self._g_side(old["g"], gprobe, n, eps)}

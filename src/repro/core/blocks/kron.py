@@ -5,16 +5,19 @@ Three concrete layouts, resolved from ``LayerMeta``'s per-side factor kinds:
   * :class:`DenseKronecker`    — both factors dense (``full``/``full``).
     The hot path: when ``kernel_backend == "pallas"`` and shapes tile, the
     decayed factor accumulation runs through the fused
-    :func:`repro.kernels.factor_update.factor_update` kernel and the
-    two-sided apply through :func:`repro.kernels.precond.precondition`.
+    :func:`repro.kernels.factor_update.factor_update` kernel (vmapped over
+    stacked layers) and the two-sided apply through
+    :func:`repro.kernels.precond.precondition`.
   * :class:`BlockDiagKronecker` — at least one TP-blocked side (DESIGN §3).
   * :class:`DiagFactor`         — at least one diagonal side (dims above
     ``max_factor_dim``).
 
 All three share the per-side numerics in ``core.factors`` / ``core.inverse``;
 the subclasses differ in dispatch and in which paths may route to Pallas.
-Ragged shapes (or sides without raw activations) silently fall back to the
-einsum path, so the choice of backend never changes results — only kernels.
+Ragged shapes (or sides without raw activations) fall back to the einsum
+path, so the choice of backend never changes results — only kernels.  Each
+block records the route every op took (``CurvatureBlock.routes``;
+``route_counts`` sums them), so a fallback is visible.
 """
 from __future__ import annotations
 
@@ -23,7 +26,7 @@ import jax.numpy as jnp
 
 from repro.core import factors as F
 from repro.core.blocks.base import CurvatureBlock, register
-from repro.kernels.compat import tile_ok
+from repro.kernels.backend import tile_ok
 from repro.kernels.factor_update import factor_update
 from repro.kernels.precond import precondition as precond_kernel
 from repro.kernels.rotate_rescale import rotate_rescale
@@ -83,40 +86,47 @@ class DenseKronecker(KroneckerPair):
 
     # -- fused stats accumulation (S5 through the factor_update kernel) --
     def _pallas_side(self, x, old, alpha, eps):
-        """One side's fused ``C ← ε C + α XᵀX`` if X tiles, else None."""
+        """One side's fused ``C ← ε C + α XᵀX`` if X tiles, else None.
+        Stacked (scanned) layers map the kernel over their lead dims."""
         if x is None:
             return None
-        x2 = x.reshape(-1, x.shape[-1])
-        if not tile_ok(*x2.shape):
+        nl = len(self.lead)
+        x2 = x.reshape(*x.shape[:nl], -1, x.shape[-1])
+        if not tile_ok(*x2.shape[nl:]):
             return None
-        cfg = self._tuned("factor_update", x2.shape, x2.dtype)
-        return factor_update(x2, old, alpha=alpha, beta=eps,
-                             interpret=self._interpret(), **cfg)
+        cfg = self._tuned("factor_update", x2.shape[nl:], x2.dtype)
+        fn = lambda xx, cc: factor_update(xx, cc, alpha=alpha, beta=eps,
+                                          interpret=self._interpret(), **cfg)
+        for _ in range(nl):
+            fn = jax.vmap(fn)
+        return fn(x2, old)
 
     def _g_side(self, old_g, gprobe, n, eps):
         """G side of the decayed blend: cotangents of the (1/N)-normalized
         sampled loss; per-token g = N·cot, so G = (1/N) Σ g gᵀ = N Σ cot cotᵀ.
         A fused ``{"gg"}`` gprobe arrives pre-contracted by the backward."""
         one = jnp.float32(1.0)
-        if isinstance(gprobe, dict):
-            return eps * old_g + (one - eps) * gprobe["gg"] * float(n)
-        cot = jax.lax.stop_gradient(gprobe)
-        g_new = self._pallas_side(cot, old_g, (one - eps) * n, eps)
+        g_new = None
+        if not isinstance(gprobe, dict):
+            g_new = self._pallas_side(jax.lax.stop_gradient(gprobe), old_g,
+                                      (one - eps) * n, eps)
+        self._route("factor_update.g", g_new is not None)
         if g_new is None:
-            g_new = (eps * old_g
-                     + (one - eps) * F.g_from_cotangent(gprobe, self.meta, n))
+            g_c = (gprobe["gg"] * float(n) if isinstance(gprobe, dict)
+                   else F.g_from_cotangent(gprobe, self.meta, n))
+            g_new = eps * old_g + (one - eps) * g_c
         return g_new
 
     def update_factors(self, old, rec, gprobe, batch, n, eps):
-        if self.backend != "pallas" or self.lead:
+        if self.backend != "pallas":
             return super().update_factors(old, rec, gprobe, batch, n, eps)
         one = jnp.float32(1.0)
         # A side: fuse only when the raw activations were recorded (models
         # that contract Ā in-forward never materialize X outside the scan)
         a_new = self._pallas_side(rec.get("a"), old["a"], (one - eps) / n, eps)
+        self._route("factor_update.a", a_new is not None)
         if a_new is None:
-            a_c = (rec["aa"] / n if "aa" in rec else
-                   F.outer_sum(rec["a"], "full", 1) / n)
+            a_c = self.stats_contrib(rec, gprobe, batch, n)["a"]
             a_new = eps * old["a"] + (one - eps) * a_c
         return {"a": a_new, "g": self._g_side(old["g"], gprobe, n, eps)}
 
@@ -130,6 +140,7 @@ class DenseKronecker(KroneckerPair):
                 a_i, vv, g_i, interpret=self._interpret(), **cfg)
             for _ in range(v.ndim - 2):      # vmap over stack/expert dims
                 fn = jax.vmap(fn)
+            self._route("precond", True)
             return fn(inv["a_inv"], v.astype(jnp.float32), inv["g_inv"])
         return super().precondition(inv, v)
 
@@ -141,6 +152,7 @@ class DenseKronecker(KroneckerPair):
                 and v.shape == (m.a_dim, m.g_dim)):
             cfg = self._tuned("update_chain", (m.a_dim, m.g_dim),
                               jnp.float32)
+            self._route("update_chain", True)
             return chain_kernel(inv["a_inv"], v.astype(jnp.float32),
                                 inv["g_inv"], mom, alpha=alpha, mu=mu,
                                 interpret=self._interpret(), **cfg)
@@ -158,6 +170,7 @@ class DenseKronecker(KroneckerPair):
                 **cfg)
             for _ in range(v.ndim - 2):      # vmap over stack/expert dims
                 fn = jax.vmap(fn)
+            self._route("rotate_rescale", True)
             return fn(eig["qa"], v.astype(jnp.float32), eig["qg"],
                       eig["s"] + eig["damp"])
         return super().precondition_eigen(eig, v)

@@ -34,7 +34,7 @@ import jax.numpy as jnp
 
 from repro.core.tags import LayerMeta
 from repro.kernels.autotune import tuned
-from repro.kernels.compat import tile_ok
+from repro.kernels.backend import tile_ok
 from repro.kernels.factor_update import factor_update
 
 

@@ -41,20 +41,40 @@ _TINY = 1e-20
 # traces / pi
 # ---------------------------------------------------------------------------
 
+def fixed_order_sum(x):
+    """Sum over the last axis in one fixed pairwise order.
+
+    A reduce's summation order is the compiler's choice, and it differs
+    between a top-level program and a ``lax.cond`` branch — the shape the
+    sharded refresh runs each block in.  The sums that feed ``eigh`` and
+    Newton–Schulz therefore add halves elementwise instead, so both
+    refresh executors see bitwise-identical inputs (docs/distributed.md).
+    """
+    n = x.shape[-1]
+    width = 1 << max(n - 1, 0).bit_length()
+    x = jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, width - n)])
+    while x.shape[-1] > 1:
+        half = x.shape[-1] // 2
+        x = x[..., :half] + x[..., half:]
+    return x[..., 0]
+
+
 def factor_trace(arr, kind: str):
     """Total trace per (stack/expert) index. Returns shape = lead dims."""
     if kind == "diag":
-        return jnp.sum(arr, axis=-1)
-    tr = jnp.trace(arr, axis1=-2, axis2=-1)
+        return fixed_order_sum(arr)
+    tr = fixed_order_sum(jnp.diagonal(arr, axis1=-2, axis2=-1))
     if kind == "block":
-        tr = jnp.sum(tr, axis=-1)          # sum over the block axis
+        tr = fixed_order_sum(tr)           # sum over the block axis
     return tr
 
 
 def pi_trace(a, a_kind, a_dim, g, g_kind, g_dim):
-    """Paper S6.3 trace-norm pi, batched over lead dims."""
-    a_tr = factor_trace(a, a_kind) / a_dim
-    g_tr = factor_trace(g, g_kind) / g_dim
+    """Paper S6.3 trace-norm pi, batched over lead dims.  The means scale
+    by a reciprocal: jit rewrites a division by a constant that way, so
+    spelling it out keeps every executor on the same bits."""
+    a_tr = factor_trace(a, a_kind) * (1.0 / a_dim)
+    g_tr = factor_trace(g, g_kind) * (1.0 / g_dim)
     return jnp.sqrt(jnp.maximum(a_tr, _TINY) / jnp.maximum(g_tr, _TINY))
 
 
@@ -83,14 +103,14 @@ def ns_inverse(m, iters: int, x0=None):
     """Newton–Schulz: X <- X (2I - M X).  m: (..., d, d) SPD (damped)."""
     d = m.shape[-1]
     eye = jnp.eye(d, dtype=m.dtype)
-    lam = jnp.max(jnp.sum(jnp.abs(m), axis=-1), axis=-1)       # >= sigma_max
+    lam = jnp.max(fixed_order_sum(jnp.abs(m)), axis=-1)        # >= sigma_max
     cold = eye / lam[..., None, None]
     if x0 is None:
         x = cold
     else:
         # safeguard the hot start: ||I - M x0||_inf < 1 required
         r = eye - m @ x0
-        bad = jnp.max(jnp.sum(jnp.abs(r), axis=-1), axis=-1) >= 1.0
+        bad = jnp.max(fixed_order_sum(jnp.abs(r)), axis=-1) >= 1.0
         x = jnp.where(bad[..., None, None], cold, x0)
 
     def body(_, x):

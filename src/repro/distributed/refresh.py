@@ -29,10 +29,10 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.distributed.plan import CHAIN, RefreshPlan, build_plan
+from repro.launch.mesh import make_mesh
 
 AXIS = "shard"
 
@@ -42,7 +42,7 @@ def flat_refresh_mesh(mesh: Optional[Mesh] = None) -> Mesh:
     local devices when training runs meshless, e.g. CPU tests)."""
     devs = (np.asarray(mesh.devices).reshape(-1) if mesh is not None
             else np.asarray(jax.devices()))
-    return Mesh(devs, (AXIS,))
+    return make_mesh((devs.size,), (AXIS,), devices=devs)
 
 
 def _zeros_like_shape(shapes):
@@ -107,8 +107,8 @@ def build_sharded_refresh(engine, mesh: Optional[Mesh] = None,
                 (factors, gamma))
         return jax.lax.psum(out, AXIS)
 
-    mapped = shard_map(_sharded, mesh=fmesh, in_specs=(P(), P(), P()),
-                       out_specs=P(), check_rep=False)
+    mapped = jax.shard_map(_sharded, mesh=fmesh, in_specs=(P(), P(), P()),
+                           out_specs=P(), check_vma=False)
     jitted = jax.jit(mapped)
 
     def refresh(factors, gamma, prev=None):

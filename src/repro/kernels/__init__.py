@@ -12,7 +12,12 @@
 #                     continuous-batching slot masks its own prefix), with
 #                     sliding-window and softcap support for gemma2-style
 #                     local layers
-# ops.py exposes jit'd wrappers with a pure-jnp fallback; ref.py holds the
-# oracles the tests sweep against (interpret=True on CPU); compat.py shims
-# renamed Pallas TPU APIs across JAX versions and hosts the tile_ok gate
-# the curvature blocks (core/blocks) use before routing onto these kernels.
+#   flash_decode_paged — the same against a shared page pool through a
+#                     page table (the serving engine's paged route)
+#   update_chain    — fused precondition + momentum + ΣD² for the fixed-lr
+#                     update; patch_factor — fused im2col + KFC factor update
+# ops.py routes decode attention onto the kernels on a TPU (the einsum
+# oracle elsewhere); ref.py holds the oracles the tests sweep against
+# (interpret mode on CPU); backend.py resolves interpret mode from the
+# live backend at call time and hosts the tile_ok gate the curvature
+# blocks (core/blocks) use before routing onto these kernels.

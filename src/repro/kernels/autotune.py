@@ -165,15 +165,14 @@ def candidates(kernel: str, shape) -> List[dict]:
         return [{"bt": bt} for bt in (64, 128, 256, 512)
                 if bt <= t_out and t_out % bt == 0 and taps <= bt * stride]
     if kernel == "flash_decode_paged":
-        # q-head block: heads of one KV group share the streamed page, so
-        # bh > 1 amortizes the per-page DMA across the group.  Legal bh
-        # divide the GQA group size (block index maps stay group-pure).
+        # KV-head block: each grid step streams one page slice for hb KV
+        # heads (each with its whole query group), so hb > 1 trades fewer
+        # grid steps for larger DMAs.  Legal hb divide the KV head count.
         b, hq, hkv, hd, nb, page = shape
-        group = hq // max(hkv, 1)
         if hd % 8 != 0 or hkv == 0 or hq % hkv != 0:
             return []
-        return [{"bh": bh} for bh in (1, 2, 4, 8, 16)
-                if bh <= group and group % bh == 0]
+        return [{"hb": hb} for hb in (1, 2, 3, 4, 8, 16)
+                if hb <= hkv and hkv % hb == 0]
     return []
 
 
@@ -257,8 +256,8 @@ def _make_runner(kernel: str, shape, dtype, interpret: bool,
         b, hq, hkv, hd, nb, page = shape
         num_pages = 1 + b * nb
         q, kp, vp = _bench_inputs(
-            6, [(b, hq, hd), (num_pages, page, hkv, hd),
-                (num_pages, page, hkv, hd)], [dtype] * 3)
+            6, [(b, hq, hd), (num_pages, hkv, page, hd),
+                (num_pages, hkv, page, hd)], [dtype] * 3)
         rs = jax.random.split(jax.random.PRNGKey(7), 1)[0]
         pt = jax.random.permutation(
             rs, jnp.arange(1, num_pages, dtype=jnp.int32)

@@ -20,7 +20,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
+from repro.kernels.backend import resolve_interpret
 
 
 def _kernel(ab_ref, xa_ref, xb_ref, c_ref, o_ref, acc_ref, *, k_steps):
@@ -39,7 +39,7 @@ def _kernel(ab_ref, xa_ref, xb_ref, c_ref, o_ref, acc_ref, *, k_steps):
 
 
 def factor_update(x, c, *, alpha, beta, bm: int = 128,
-                  bn: int = 128, bk: int = 128, interpret: bool = True):
+                  bn: int = 128, bk: int = 128, interpret=None):
     """x: (N, d) activations/gradients; c: (d, d) running factor.
 
     ``alpha``/``beta`` may be python floats or traced jnp scalars.
@@ -67,7 +67,7 @@ def factor_update(x, c, *, alpha, beta, bm: int = 128,
             scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         ),
         out_shape=jax.ShapeDtypeStruct((d, d), jnp.float32),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(ab, x, x, c)

@@ -18,7 +18,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
+from repro.kernels.backend import resolve_interpret
 
 NEG_INF = -1e30
 
@@ -66,7 +66,7 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
 
 def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
                     cap: float = 0.0, bq: int = 128, bk: int = 128,
-                    interpret: bool = True):
+                    interpret=None):
     """q: (B, Hq, Tq, hd);  k, v: (B, Hkv, Tk, hd).  Returns (B, Hq, Tq, hd)."""
     b, hq, tq, hd = q.shape
     _, hkv, tk, _ = k.shape
@@ -97,8 +97,8 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, hd), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(q, k, v)

@@ -17,7 +17,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
+from repro.kernels.backend import resolve_interpret
 
 
 DEFAULT_BLOCK = 128
@@ -42,7 +42,7 @@ def _kernel(a_ref, b_ref, c_ref, o_ref, acc_ref, *, alpha, beta, k_steps):
 def matmul(a, b, c=None, *, alpha: float = 1.0, beta: float = 0.0,
            bm: int = DEFAULT_BLOCK, bn: int = DEFAULT_BLOCK,
            bk: int = DEFAULT_BLOCK, out_dtype=jnp.float32,
-           interpret: bool = True):
+           interpret=None):
     """a: (M, K); b: (K, N); c: optional (M, N). Dims must tile evenly."""
     m, k = a.shape
     k2, n = b.shape
@@ -68,7 +68,7 @@ def matmul(a, b, c=None, *, alpha: float = 1.0, beta: float = 0.0,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(a, b, c)

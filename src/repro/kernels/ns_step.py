@@ -14,14 +14,14 @@ import jax.numpy as jnp
 from repro.kernels.matmul import matmul
 
 
-def ns_step(m, x, *, block: int = 128, interpret: bool = True):
+def ns_step(m, x, *, block: int = 128, interpret=None):
     """One Newton–Schulz iteration for M⁻¹. m, x: (d, d)."""
     z = matmul(m, x, bm=block, bn=block, bk=block, interpret=interpret)
     return matmul(x, z, c=x, alpha=-1.0, beta=2.0, bm=block, bn=block,
                   bk=block, interpret=interpret)
 
 
-def ns_inverse(m, iters: int, *, block: int = 128, interpret: bool = True):
+def ns_inverse(m, iters: int, *, block: int = 128, interpret=None):
     """Full inversion: cold start X0 = I/‖M‖_inf, then `iters` steps."""
     d = m.shape[-1]
     lam = jnp.max(jnp.sum(jnp.abs(m), axis=-1))

@@ -1,65 +1,32 @@
-"""jit'd dispatch layer over the Pallas kernels.
+"""Dispatch layer over the decode kernels, with the einsum oracle beside.
 
-``use_pallas(True)`` (or REPRO_USE_PALLAS=1) routes the hot ops through the
-kernels — compiled on TPU, interpret-mode on CPU; the default is the pure-jnp
-path, which XLA fuses well on CPU and doubles as the reference
-implementation.  On a real TPU deployment the launcher flips this on.
+On a TPU the Pallas kernel runs, compiled, wherever its shape applies;
+elsewhere the masked einsum oracle runs.  Both are decided when a call is
+traced, never when this module is imported.  ``use_pallas(on, interpret)``
+overrides the choice — the CPU tests use it to drive the kernels in
+interpret mode — and ``use_pallas(None)`` restores the backend default.
 """
 from __future__ import annotations
-
-import os
 
 import jax
 import jax.numpy as jnp
 
-from repro.kernels import flash_attention as _fa
+from repro.kernels import autotune as _at
 from repro.kernels import flash_decode as _fd
-from repro.kernels import factor_update as _fu
-from repro.kernels import matmul as _mm
-from repro.kernels import ns_step as _ns
-from repro.kernels import precond as _pc
-from repro.kernels import ref as _ref
+from repro.kernels.backend import on_tpu, resolve_interpret
 
-_STATE = {"use_pallas": os.environ.get("REPRO_USE_PALLAS", "0") == "1",
-          "interpret": jax.default_backend() != "tpu"}
+_STATE = {"use_pallas": None, "interpret": None}
 
 
-def use_pallas(on: bool = True, interpret=None):
+def use_pallas(on=True, interpret=None):
+    """Force the kernel route on (or off); ``None`` = the backend default."""
     _STATE["use_pallas"] = on
-    if interpret is not None:
-        _STATE["interpret"] = interpret
+    _STATE["interpret"] = interpret
 
 
 def enabled() -> bool:
-    return _STATE["use_pallas"]
-
-
-def matmul(a, b, c=None, *, alpha=1.0, beta=0.0):
-    if enabled() and all(s % 8 == 0 for s in (*a.shape, *b.shape)):
-        return _mm.matmul(a, b, c, alpha=alpha, beta=beta,
-                          interpret=_STATE["interpret"])
-    return _ref.matmul_ref(a, b, c, alpha=alpha, beta=beta)
-
-
-def factor_update(x, c, *, alpha, beta):
-    """C <- beta C + alpha XᵀX (the S5 decayed running-average update)."""
-    if enabled() and x.shape[0] % 8 == 0 and x.shape[1] % 8 == 0:
-        return _fu.factor_update(x, c, alpha=alpha, beta=beta,
-                                 interpret=_STATE["interpret"])
-    return _ref.factor_update_ref(x, c, alpha=alpha, beta=beta)
-
-
-def ns_inverse(m, iters: int):
-    if enabled() and m.shape[-1] % 8 == 0 and m.ndim == 2:
-        return _ns.ns_inverse(m, iters, interpret=_STATE["interpret"])
-    return _ref.ns_inverse_ref(m, iters)
-
-
-def precondition(a_inv, v, g_inv):
-    if enabled() and all(s % 8 == 0 for s in v.shape):
-        return _pc.precondition(a_inv, v, g_inv,
-                                interpret=_STATE["interpret"])
-    return _ref.precondition_ref(a_inv, v, g_inv)
+    on = _STATE["use_pallas"]
+    return on_tpu() if on is None else on
 
 
 def flash_decode_ref(q, k, v, lengths, *, window=0, cap=0.0):
@@ -106,15 +73,15 @@ def flash_decode(q, k, v, lengths, *, bk=128, window=0, cap=0.0):
 
 def paged_gather(k_pool, v_pool, page_table):
     """Materialize the dense ``(B, Hkv, S_view, hd)`` gather view of a page
-    pool — the serving engine's *oracle* decode route (and the paged
-    kernel's differential reference), no longer its hot path."""
+    pool ``(num_pages, Hkv, page, hd)`` — the serving engine's *oracle*
+    decode route (and the paged kernel's differential reference)."""
     nb = page_table.shape[1]
-    num_pages, page, hkv, hd = k_pool.shape
+    num_pages, hkv, page, hd = k_pool.shape
     b = page_table.shape[0]
 
     def one(pool):
-        g = jnp.take(pool, page_table, axis=0)       # (B, nb, P, hkv, hd)
-        return g.reshape(b, nb * page, hkv, hd).transpose(0, 2, 1, 3)
+        g = jnp.take(pool, page_table, axis=0)       # (B, nb, hkv, P, hd)
+        return g.transpose(0, 2, 1, 3, 4).reshape(b, hkv, nb * page, hd)
 
     return one(k_pool), one(v_pool)
 
@@ -122,38 +89,25 @@ def paged_gather(k_pool, v_pool, page_table):
 def flash_decode_paged(q, k_pool, v_pool, lengths, page_table, *, window=0,
                        cap=0.0, tune_mode: str = "off"):
     """Block-indexed paged decode: (B,Hq,hd) against a shared page pool
-    ``(num_pages, page_size, Hkv, hd)`` through each row's ``(max_blocks,)``
-    page-table row.  The Pallas route walks the pages in place (page table
-    as a scalar-prefetch operand — no dense gather view); the XLA fallback
-    gathers the view and runs the einsum oracle, so fallback == oracle by
-    construction.  ``tune_mode`` threads the autotuner (``REPRO_AUTOTUNE``
-    env overrides) for the q-head block ``bh``."""
+    ``(num_pages, Hkv, page_size, hd)`` through each row's
+    ``(max_blocks,)`` page-table row.  The Pallas route walks the pages in
+    place (page table as a scalar-prefetch operand — no dense gather
+    view); the XLA fallback gathers the view and runs the einsum oracle, so
+    fallback == oracle by construction.  ``tune_mode`` threads the
+    autotuner (``REPRO_AUTOTUNE`` env overrides) for the KV-head block
+    ``hb``."""
     b, hq, hd = q.shape
     lengths = jnp.broadcast_to(jnp.asarray(lengths, jnp.int32).reshape(-1),
                                (b,))
     page_table = jnp.asarray(page_table, jnp.int32)
     if enabled() and hd % 8 == 0:
-        kw = {}
-        from repro.kernels import autotune as _at
-        hkv, page = k_pool.shape[2], k_pool.shape[1]
+        interpret = resolve_interpret(_STATE["interpret"])
+        hkv, page = k_pool.shape[1], k_pool.shape[2]
         cfg = _at.tuned("flash_decode_paged",
                         (b, hq, hkv, hd, page_table.shape[1], page),
-                        q.dtype, interpret=_STATE["interpret"],
-                        mode=tune_mode)
-        if cfg:
-            kw.update(cfg)
+                        q.dtype, interpret=interpret, mode=tune_mode) or {}
         return _fd.flash_decode_paged(q, k_pool, v_pool, lengths, page_table,
                                       window=window, cap=cap,
-                                      interpret=_STATE["interpret"], **kw)
+                                      interpret=interpret, **cfg)
     kd, vd = paged_gather(k_pool, v_pool, page_table)
     return flash_decode_ref(q, kd, vd, lengths, window=window, cap=cap)
-
-
-def flash_attention(q, k, v, *, causal=True, window=0, cap=0.0):
-    """(B, Hq, Tq, hd) x (B, Hkv, Tk, hd) -> (B, Hq, Tq, hd)."""
-    tq, tk, hd = q.shape[2], k.shape[2], q.shape[3]
-    if (enabled() and tq % 8 == 0 and tk % 128 == 0 and hd % 8 == 0):
-        return _fa.flash_attention(q, k, v, causal=causal, window=window,
-                                   cap=cap, interpret=_STATE["interpret"])
-    return _ref.flash_attention_ref(q, k, v, causal=causal, window=window,
-                                    cap=cap)

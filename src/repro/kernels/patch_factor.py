@@ -9,11 +9,13 @@ The A-factor of a 1-D conv has a tap-pair block structure
 
 so the kernel grids over tap pairs ``(k₁, k₂)`` and streams time tiles of
 the *raw* input through VMEM once per pair: each step loads two consecutive
-``(bt·s, C)`` time blocks (the second is the halo for the tap shift),
-dynamically slices the tap offset, subsamples the stride in-register, and
-feeds the MXU a ``(bt, C)ᵀ @ (bt, C)`` rank-update.  The decay blend is the
-epilogue of the last step; ``alpha``/``beta`` ride scalar prefetch so the
-optimizer's traced ``ε = min(1 − 1/k, ε_max)`` never recompiles.
+``(bt·s, C)`` time blocks (the second is the halo for the tap shift)
+into one VMEM buffer, reads the tap-shifted, stride-subsampled rows with
+one strided load, and feeds the MXU a ``(bt, C)ᵀ @ (bt, C)`` rank-update.
+The decay blend is the epilogue of the last step; ``alpha``/``beta`` ride
+scalar prefetch so the optimizer's traced ``ε = min(1 − 1/k, ε_max)``
+never recompiles.  The factor is viewed as ``(tap, tap, C, C)`` tiles, so
+each block spans its array's last two dims as the TPU lowering requires.
 
 The homogeneous bias row/column (``ā = [patch; 1]``) is a spatial *sum* of
 the raw input — O(T·C), not O(T·C²·K²) — so :func:`patch_factor_update`
@@ -30,7 +32,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams, tile_ok
+from repro.kernels.backend import resolve_interpret, tile_ok
 
 
 def conv_pad_amounts(t: int, k: int, stride: int, padding: str):
@@ -51,8 +53,8 @@ def patch_tile_ok(c: int, t_out: int, taps: int = 1,
             and taps <= min(128, t_out) * stride)
 
 
-def _kernel(ab_ref, x0_ref, x1_ref, c_ref, o_ref, acc_ref, *, bt, stride,
-            n_steps):
+def _kernel(ab_ref, x0_ref, x1_ref, c_ref, o_ref, buf_ref, acc_ref, *, bt,
+            stride, n_steps):
     ki = pl.program_id(0)
     kj = pl.program_id(1)
     r = pl.program_id(2)
@@ -62,26 +64,27 @@ def _kernel(ab_ref, x0_ref, x1_ref, c_ref, o_ref, acc_ref, *, bt, stride,
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     # two consecutive time blocks: the halo for the (sub-block) tap shifts
-    buf = jnp.concatenate([x0_ref[0], x1_ref[0]], axis=0)   # (2·bt·s, C)
+    blk = x0_ref.shape[1]
+    buf_ref[pl.ds(0, blk), :] = x0_ref[0]
+    buf_ref[pl.ds(blk, blk), :] = x1_ref[0]
 
     def rows(k):
-        w = jax.lax.dynamic_slice_in_dim(buf, k, bt * stride, axis=0)
-        if stride > 1:
-            w = w.reshape(bt, stride, w.shape[-1])[:, 0, :]
-        return w
+        # patch rows t·stride + k for t < bt: a strided read at the tap offset
+        return buf_ref[pl.ds(k, bt, stride=stride), :]
 
-    acc_ref[...] += jnp.dot(rows(ki).T, rows(kj),
-                            preferred_element_type=jnp.float32)
+    acc_ref[...] += jax.lax.dot_general(
+        rows(ki), rows(kj), (((0,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)
 
     @pl.when(r == n_steps - 1)
     def _done():
-        o_ref[...] = (ab_ref[0] * acc_ref[...]
-                      + ab_ref[1] * c_ref[...].astype(jnp.float32)
-                      ).astype(o_ref.dtype)
+        o_ref[0, 0] = (ab_ref[0] * acc_ref[...]
+                       + ab_ref[1] * c_ref[0, 0].astype(jnp.float32)
+                       ).astype(o_ref.dtype)
 
 
 def patch_factor(x, c, *, taps: int, stride: int, t_out: int, alpha, beta,
-                 bt: int = 128, interpret: bool = True):
+                 bt: int = 128, interpret=None):
     """x: (B, T_pad, C) conv-padded raw input; c: (K·C, K·C) running factor.
 
     Patch row ``(b, t, k)`` is ``x[b, t·stride + k]`` for ``t < t_out``;
@@ -103,7 +106,8 @@ def patch_factor(x, c, *, taps: int, stride: int, t_out: int, alpha, beta,
     ab = jnp.stack([jnp.asarray(alpha, jnp.float32),
                     jnp.asarray(beta, jnp.float32)])
     kernel = functools.partial(_kernel, bt=bt, stride=stride, n_steps=n_steps)
-    return pl.pallas_call(
+    c4 = c.reshape(taps, ch, taps, ch).transpose(0, 2, 1, 3)
+    out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
@@ -113,20 +117,23 @@ def patch_factor(x, c, *, taps: int, stride: int, t_out: int, alpha, beta,
                              lambda i, j, r, ab: (r // nt, r % nt, 0)),
                 pl.BlockSpec((1, blk, ch),
                              lambda i, j, r, ab: (r // nt, r % nt + 1, 0)),
-                pl.BlockSpec((ch, ch), lambda i, j, r, ab: (i, j)),
+                pl.BlockSpec((1, 1, ch, ch), lambda i, j, r, ab: (i, j, 0, 0)),
             ],
-            out_specs=pl.BlockSpec((ch, ch), lambda i, j, r, ab: (i, j)),
-            scratch_shapes=[pltpu.VMEM((ch, ch), jnp.float32)],
+            out_specs=pl.BlockSpec((1, 1, ch, ch),
+                                   lambda i, j, r, ab: (i, j, 0, 0)),
+            scratch_shapes=[pltpu.VMEM((2 * blk, ch), x.dtype),
+                            pltpu.VMEM((ch, ch), jnp.float32)],
         ),
-        out_shape=jax.ShapeDtypeStruct((d, d), jnp.float32),
-        compiler_params=CompilerParams(
+        out_shape=jax.ShapeDtypeStruct((taps, taps, ch, ch), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
-    )(ab, x, x, c)
+        interpret=resolve_interpret(interpret),
+    )(ab, x, x, c4)
+    return out.transpose(0, 2, 1, 3).reshape(d, d)
 
 
 def patch_factor_update(x, old, meta, alpha, beta, *, bt: int = 128,
-                        interpret: bool = True,
+                        interpret=None,
                         autotune_mode: str = "off"):
     """The ``ConvKronecker`` A-side route: fused ``Ā ← β Ā + α P̂ᵀP̂`` for a
     1-D conv from the raw input, or ``None`` when the shape doesn't tile
@@ -136,6 +143,7 @@ def patch_factor_update(x, old, meta, alpha, beta, *, bt: int = 128,
     with the homogeneous row/column last when ``meta.has_bias``.
     ``autotune_mode`` != "off" looks up a tuned time-tile ``bt``.
     """
+    interpret = resolve_interpret(interpret)
     if len(meta.conv_spatial) != 1:
         return None
     (k,), (s,) = meta.conv_spatial, meta.conv_stride

@@ -11,7 +11,7 @@ the damped diagonal as it is produced, never re-read.  ``lam`` rides a
 scalar-prefetch operand and may be a traced value (the damping floor /
 per-refresh λ), mirroring ``factor_update``'s traced decay ε.
 
-Shapes must tile into the 128-blocks (``compat.tile_ok``); the curvature
+Shapes must tile into the 128-blocks (``backend.tile_ok``); the curvature
 blocks fall back to the einsum path in ``core.inverse.apply_eigen`` for
 ragged shapes or ``kernel_backend="xla"``, so the backend knob never changes
 results — only which kernels execute.
@@ -25,7 +25,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
+from repro.kernels.backend import resolve_interpret
 from repro.kernels.matmul import matmul
 
 DEFAULT_BLOCK = 128
@@ -48,7 +48,7 @@ def _kernel(lam_ref, a_ref, b_ref, s_ref, o_ref, acc_ref, *, k_steps):
 
 def matmul_rescale(a, b, s, lam, *, bm: int = DEFAULT_BLOCK,
                    bn: int = DEFAULT_BLOCK, bk: int = DEFAULT_BLOCK,
-                   interpret: bool = True):
+                   interpret=None):
     """``(A @ B) / (S + lam)`` — a: (M, K); b: (K, N); s: (M, N).
 
     ``lam`` may be a python float or a traced jnp scalar (scalar prefetch).
@@ -77,14 +77,14 @@ def matmul_rescale(a, b, s, lam, *, bm: int = DEFAULT_BLOCK,
             scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         ),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(lam, a, b, s)
 
 
 def rotate_rescale(qa, v, qg, s, lam=0.0, *, block: int = DEFAULT_BLOCK,
-                   interpret: bool = True):
+                   interpret=None):
     """qa: (d_in, d_in); v: (d_in, d_out); qg: (d_out, d_out); s: (d_in, d_out).
 
     Four tiled matmuls; the rescale fuses into the second's epilogue.

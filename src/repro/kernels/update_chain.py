@@ -28,7 +28,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
+from repro.kernels.backend import resolve_interpret
 from repro.kernels.matmul import matmul
 
 DEFAULT_BLOCK = 128
@@ -47,16 +47,18 @@ def _kernel(am_ref, a_ref, t_ref, m_ref, o_ref, sq_ref, acc_ref, *, k_steps):
         out = (am_ref[0] * acc_ref[...]
                + am_ref[1] * m_ref[...].astype(jnp.float32))
         o_ref[...] = out.astype(o_ref.dtype)
-        sq_ref[0, 0] = jnp.sum(out * out)
+        sq_ref[...] = jnp.sum(out * out).reshape(sq_ref.shape)
 
 
 def axpy_momentum(a_inv, t, mom, alpha, mu, *, bm: int = DEFAULT_BLOCK,
                   bn: int = DEFAULT_BLOCK, bk: int = DEFAULT_BLOCK,
-                  interpret: bool = True):
+                  interpret=None):
     """``D = alpha·(a_inv @ t) + mu·mom`` plus per-tile ``Σ D²`` partials.
 
     a_inv: (M, K); t: (K, N); mom: (M, N).  Returns ``(D, sq_partials)``
-    with ``sq_partials`` shaped ``(M//bm, N//bn)``.  ``alpha``/``mu`` may be
+    with ``sq_partials`` shaped ``(M//bm, N//bn, 1, 1)``: one ``(1, 1)``
+    block per tile, so the block spans the array's last two dims as the
+    TPU lowering requires.  ``alpha``/``mu`` may be
     python floats or traced jnp scalars (scalar prefetch).
     """
     m, k = a_inv.shape
@@ -82,22 +84,22 @@ def axpy_momentum(a_inv, t, mom, alpha, mu, *, bm: int = DEFAULT_BLOCK,
             ],
             out_specs=[
                 pl.BlockSpec((bm, bn), lambda i, j, kk, am: (i, j)),
-                pl.BlockSpec((1, 1), lambda i, j, kk, am: (i, j)),
+                pl.BlockSpec((1, 1, 1, 1), lambda i, j, kk, am: (i, j, 0, 0)),
             ],
             scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         ),
         out_shape=[
             jax.ShapeDtypeStruct((m, n), jnp.float32),
-            jax.ShapeDtypeStruct((m // bm, n // bn), jnp.float32),
+            jax.ShapeDtypeStruct((m // bm, n // bn, 1, 1), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(am, a_inv, t, mom)
 
 
 def precond_momentum(a_inv, v, g_inv, mom, *, alpha, mu,
-                     block: int = DEFAULT_BLOCK, interpret: bool = True):
+                     block: int = DEFAULT_BLOCK, interpret=None):
     """The fused chain for one Kronecker block:
 
         D = alpha · (A^-1 V G^-1) + mu · mom,   plus ``Σ D²`` (a scalar)

@@ -3,8 +3,9 @@
   python -m repro.launch.serve --arch smollm-135m --reduced --requests 6 \\
       --temperature 0.8 --top_k 40 --seed 7
 
-The default decode route is block-indexed paged attention
-(``--decode_route gather`` selects the dense-gather oracle for debugging);
+The default decode route is paged: block-indexed paged attention on a TPU,
+the einsum oracle over the gathered pages elsewhere (``--decode_route
+gather`` selects the dense-gather oracle on any backend);
 ``--num_pages`` shrinks the page pool to exercise eviction/preemption.
 
 ``--uncertainty`` requests per-token Laplace predictive variance: pass
@@ -25,9 +26,11 @@ from repro.configs import get_config, get_reduced_config
 from repro.models.lm import LM
 from repro.obs import ObsConfig
 from repro.serving.server import DECODE_ROUTES, Engine, Request
+from repro.utils.compile_cache import enable_compile_cache
 
 
 def main(argv=None):
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="smollm-135m")
     ap.add_argument("--reduced", action="store_true")

@@ -424,12 +424,14 @@ class LM:
             # attend the page pool in place through the page table — the
             # dense (B, S_view) gather view is never materialized.
             assert t == 1, "paged decode is one token per row"
-            page_size = cache["k"].shape[1]
+            page_size = cache["k"].shape[2]        # (pages, hkv, P, hd)
             page = jnp.take_along_axis(
                 page_table, (decode_pos // page_size)[:, None], axis=1)[:, 0]
             off = decode_pos % page_size
-            ck = cache["k"].at[page, off].set(k[:, 0].astype(cache["k"].dtype))
-            cv = cache["v"].at[page, off].set(v[:, 0].astype(cache["v"].dtype))
+            ck = cache["k"].at[page, :, off].set(
+                k[:, 0].astype(cache["k"].dtype))
+            cv = cache["v"].at[page, :, off].set(
+                v[:, 0].astype(cache["v"].dtype))
             o = ops.flash_decode_paged(q[:, 0], ck, cv, decode_pos + 1,
                                        page_table, window=window,
                                        cap=cfg.attn_softcap)
@@ -835,7 +837,7 @@ class LM:
         slot splices and attends at its own offset).
 
         With ``page_table`` (a ``(B, max_blocks)`` int32 block table) the
-        cache leaves are *page pools* ``(ng, num_pages, page_size, hkv,
+        cache leaves are *page pools* ``(ng, num_pages, hkv, page_size,
         hd)`` shared by all rows: each attention layer scatters its one new
         KV row into the slot's physical page and attends block-indexed
         through the table (``ops.flash_decode_paged``) — no dense per-row

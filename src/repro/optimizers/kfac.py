@@ -125,10 +125,11 @@ class KFACEngine:
         self.fused_names = set()
         if self.fused:
             from repro.core import fused as FU
+            from repro.kernels.backend import resolve_interpret
             cmap = getattr(model, "contract_map", None)
             gmap = getattr(model, "gcontract_map", None)
             if cmap is not None and gmap is not None:
-                interpret = jax.default_backend() != "tpu"
+                interpret = resolve_interpret(None)
                 self.fused_names = {n for n, m in self.metas.items()
                                     if FU.fused_eligible(m)}
                 for n in sorted(self.fused_names):
@@ -160,6 +161,7 @@ class KFACEngine:
             # {"gg": (d_out, d_out)} probe whose VJP cotangent is the
             # already-contracted second moment (core/fused.apply_gprobe)
             from repro.core import fused as FU
+            from repro.kernels.backend import resolve_interpret
             for n in self.fused_names:
                 probes[n] = FU.gg_probe(self.metas[n])
         return probes

@@ -3,9 +3,10 @@
 Layout
 ------
 For every attention pattern position ``posX`` of the model there is one
-``k`` and one ``v`` pool of shape ``(ng, num_pages, page_size, hkv, hd)``
+``k`` and one ``v`` pool of shape ``(ng, num_pages, hkv, page_size, hd)``
 (``ng`` = the model's scan-group leading dim; same dtype as the serve-side
-dense cache, bfloat16).  All layers share one *page-id space*: a slot's
+dense cache, bfloat16).  ``(page_size, hd)`` are the last two dims, so the
+paged kernel's page block spans them whole, as the TPU lowering requires.  All layers share one *page-id space*: a slot's
 page table row lists the physical pages backing its logical positions in
 order, and that same row indexes every layer's pools — exactly the
 vLLM-style block table, minus per-layer tables.
@@ -74,8 +75,8 @@ class PagedKVCache:
         self.dtype = dtype
         cfg = model.cfg
         self.layer_names = [f"pos{i}" for i in range(len(model.pattern))]
-        self._kv_shape = (model.n_groups, self.num_pages, page_size,
-                          cfg.n_kv_heads, cfg.hd)
+        self._kv_shape = (model.n_groups, self.num_pages, cfg.n_kv_heads,
+                          page_size, cfg.hd)
 
     def blocks_for(self, n_positions: int) -> int:
         """Pages needed to back ``n_positions`` logical cache entries."""
@@ -96,8 +97,10 @@ class PagedKVCache:
         ng = self.model.n_groups
 
         def one(pool):
-            g = jnp.take(pool, page_table, axis=1)  # (ng,B,nb,P,hkv,hd)
-            return g.reshape(ng, self.b, self.s_view, *pool.shape[3:])
+            g = jnp.take(pool, page_table, axis=1)  # (ng,B,nb,hkv,P,hd)
+            g = g.transpose(0, 1, 2, 4, 3, 5)       # (ng,B,nb,P,hkv,hd)
+            return g.reshape(ng, self.b, self.s_view, pool.shape[2],
+                             pool.shape[4])
 
         return {name: {"k": one(p["k"]), "v": one(p["v"])}
                 for name, p in pools.items()}
@@ -112,11 +115,14 @@ class PagedKVCache:
         off = pos % self.page_size
         out = {}
         for name, p in pools.items():
-            row_k = dense_cache[name]["k"][:, bidx, pos]    # (ng,B,hkv,hd)
-            row_v = dense_cache[name]["v"][:, bidx, pos]
+            # (B, ng, hkv, hd): the indexed dims lead, as ``.at`` orders them
+            row_k = jnp.swapaxes(dense_cache[name]["k"][:, bidx, pos], 0, 1)
+            row_v = jnp.swapaxes(dense_cache[name]["v"][:, bidx, pos], 0, 1)
             out[name] = {
-                "k": p["k"].at[:, page, off].set(row_k.astype(p["k"].dtype)),
-                "v": p["v"].at[:, page, off].set(row_v.astype(p["v"].dtype)),
+                "k": p["k"].at[:, page, :, off].set(
+                    row_k.astype(p["k"].dtype)),
+                "v": p["v"].at[:, page, :, off].set(
+                    row_v.astype(p["v"].dtype)),
             }
         return out
 
@@ -144,6 +150,6 @@ class PagedKVCache:
                 x = jnp.pad(x, ((0, 0), (0, 0), (0, pad), (0, 0), (0, 0)))
                 x = x.reshape(ng, nb, self.page_size, *x.shape[3:])
                 new[kv] = pools[name][kv].at[:, pids].set(
-                    x.astype(self.dtype))
+                    jnp.swapaxes(x, 2, 3).astype(self.dtype))
             out[name] = new
         return out

@@ -328,7 +328,7 @@ def test_bad_autotune_mode_rejected():
 
 
 # ---------------------------------------------------------------------------
-# paged flash-decode tuning: legal head blocks, every candidate allclose
+# paged flash-decode tuning: legal KV-head blocks, every candidate allclose
 # ---------------------------------------------------------------------------
 
 PAGED_SHAPE = (2, 8, 2, 32, 3, 8)       # (b, hq, hkv, hd, max_blocks, page)
@@ -336,12 +336,11 @@ PAGED_SHAPE = (2, 8, 2, 32, 3, 8)       # (b, hq, hkv, hd, max_blocks, page)
 
 def test_paged_decode_candidates_legal():
     b, hq, hkv, hd, nb, page = PAGED_SHAPE
-    group = hq // hkv
     cands = at.candidates("flash_decode_paged", PAGED_SHAPE)
-    assert cands and {"bh": 1} in cands
+    assert cands and {"hb": 1} in cands
     for cfg in cands:
-        assert set(cfg) == {"bh"}
-        assert cfg["bh"] <= group and group % cfg["bh"] == 0
+        assert set(cfg) == {"hb"}
+        assert cfg["hb"] <= hkv and hkv % cfg["hb"] == 0
     # ragged head dim / non-GQA head counts: no legal candidates
     assert at.candidates("flash_decode_paged", (2, 8, 2, 33, 3, 8)) == []
     assert at.candidates("flash_decode_paged", (2, 7, 2, 32, 3, 8)) == []
@@ -349,7 +348,7 @@ def test_paged_decode_candidates_legal():
 
 def test_paged_decode_every_candidate_allclose():
     """Each legal head block is the same kernel numerically — vs the
-    dense-gather einsum oracle, not just vs another bh."""
+    dense-gather einsum oracle, not just vs another hb."""
     from repro.kernels import ops
     from repro.kernels.flash_decode import flash_decode_paged
     b, hq, hkv, hd, nb, page = PAGED_SHAPE
@@ -357,9 +356,9 @@ def test_paged_decode_every_candidate_allclose():
     k = jax.random.PRNGKey(0)
     q = jax.random.normal(k, (b, hq, hd), jnp.float32)
     kp = jax.random.normal(jax.random.fold_in(k, 1),
-                           (num_pages, page, hkv, hd), jnp.float32)
+                           (num_pages, hkv, page, hd), jnp.float32)
     vp = jax.random.normal(jax.random.fold_in(k, 2),
-                           (num_pages, page, hkv, hd), jnp.float32)
+                           (num_pages, hkv, page, hd), jnp.float32)
     pt = jax.random.permutation(jax.random.fold_in(k, 3),
                                 jnp.arange(1, num_pages)).reshape(b, nb)
     lengths = jnp.asarray([page + 3, nb * page], jnp.int32)

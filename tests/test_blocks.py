@@ -209,6 +209,43 @@ def test_dense_update_factors_pallas_ragged_falls_back():
     np.testing.assert_allclose(got["g"], want["g"], rtol=1e-5, atol=1e-6)
 
 
+def test_stacked_update_factors_pallas_matches_xla():
+    """Stacked (scanned) layers map the factor_update kernel over the stack
+    on the G side; the A side, contracted in-forward ("aa"), stays einsum.
+    Both sides must equal the einsum path, and the routes say so."""
+    meta = _meta(d_in=32, d_out=16, n_stack=3)
+    n = 64
+    xa = jax.random.normal(jax.random.PRNGKey(30), (3, n, meta.a_dim))
+    rec = {"aa": jnp.einsum("snd,sne->sde", xa, xa)}
+    cot = jax.random.normal(jax.random.PRNGKey(31), (3, n, meta.g_dim)) / n
+    old = {"a": jnp.stack([_spd(32 + i, meta.a_dim) for i in range(3)]),
+           "g": jnp.stack([_spd(35 + i, meta.g_dim) for i in range(3)])}
+    blk_x = B.resolve(meta)(meta, CFG)
+    blk_p = B.resolve(meta)(meta, CFG_PALLAS)
+    want = blk_x.update_factors(old, rec, cot, {}, n, jnp.float32(0.9))
+    got = jax.jit(lambda eps: blk_p.update_factors(old, rec, cot, {}, n,
+                                                   eps))(jnp.float32(0.9))
+    np.testing.assert_allclose(got["a"], want["a"], rtol=1e-5, atol=1e-6)
+    np.testing.assert_allclose(got["g"], want["g"], rtol=1e-4, atol=1e-5)
+    assert blk_p.routes == {"factor_update.a": "einsum",
+                            "factor_update.g": "pallas"}
+    assert B.route_counts({"x": blk_x, "p": blk_p}) == {
+        "factor_update.a": {"pallas": 0, "einsum": 2},
+        "factor_update.g": {"pallas": 1, "einsum": 1}}
+
+
+@pytest.mark.parametrize("d_in,d_out,route", [(64, 32, "pallas"),
+                                              (13, 9, "einsum")])
+def test_precondition_route_is_recorded(d_in, d_out, route):
+    """A shape that does not tile declines to einsum — and says so."""
+    meta = _meta(d_in=d_in, d_out=d_out)
+    blk = B.resolve(meta)(meta, CFG_PALLAS)
+    inv = {"a_inv": _spd(40, meta.a_dim), "g_inv": _spd(41, meta.g_dim)}
+    v = jax.random.normal(jax.random.PRNGKey(42), (meta.a_dim, meta.g_dim))
+    blk.precondition(inv, v)
+    assert blk.routes == {"precond": route}
+
+
 def test_dense_precondition_pallas_matches_xla():
     meta = _meta(d_in=64, d_out=32)
     a, g = _spd(23, meta.a_dim), _spd(24, meta.g_dim)

@@ -24,8 +24,9 @@ _SCRIPT = textwrap.dedent("""
 
     arch = sys_arch = "{arch}"
     multi_pod = {multi_pod}
-    mesh = (jax.make_mesh((2, 2, 2), ("pod", "data", "model")) if multi_pod
-            else jax.make_mesh((4, 2), ("data", "model")))
+    from repro.launch.mesh import make_mesh
+    mesh = (make_mesh((2, 2, 2), ("pod", "data", "model")) if multi_pod
+            else make_mesh((4, 2), ("data", "model")))
     cfg = get_reduced_config(arch)
     shape = ShapeConfig("t", 32, 8, "train")
     kcfg = KFACConfig(max_factor_dim=64, inv_mode="{inv_mode}")

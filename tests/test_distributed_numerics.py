@@ -91,7 +91,8 @@ _ELASTIC = _PRELUDE + textwrap.dedent("""
     from repro.training.elastic import remesh_plan, reshard
 
     # an 8-device pod, FSDP(data=4) x TP(model=2)
-    old_mesh = jax.make_mesh((4, 2), ("data", "model"))
+    from repro.launch.mesh import make_mesh
+    old_mesh = make_mesh((4, 2), ("data", "model"))
     cfg = KFACConfig(inv_mode="blkdiag", inverse_method="eigh",
                      lambda_init=1.0)
     mlp, params, data = problem()
@@ -110,8 +111,8 @@ _ELASTIC = _PRELUDE + textwrap.dedent("""
 
     # the pod shrank: rebuild on 4 of the 8 hosts' devices, same logical
     # layout — remesh_plan maps the PartitionSpec tree onto the new mesh
-    new_mesh = jax.sharding.Mesh(
-        np.asarray(jax.devices()[:4]).reshape(2, 2), ("data", "model"))
+    new_mesh = make_mesh((2, 2), ("data", "model"),
+                         devices=jax.devices()[:4])
     specs = jax.tree.map(lambda sh: sh.spec, state_sh)
     new_sh = remesh_plan(old_mesh, new_mesh, specs)
     state4 = reshard(state8, new_sh)

@@ -35,10 +35,13 @@ CHECKPOINTS = (0, 9, 19, 29, 39, 49)
 # mode -> loss at each checkpoint step, from the run this file documents.
 # Bands: rel=7% per checkpoint (platform spread on CPU f32 is <0.5%; an
 # optimizer regression is an order of magnitude outside this).
+# Pinned under JAX 0.9's default jax_threefry_partitionable=True, which
+# draws different random numbers (init, data, sampled targets) than the
+# old default; with the flag off the previous pins still hold.
 GOLDEN = {
-    "blkdiag": (93.1689, 42.0944, 36.7356, 32.6663, 29.4025, 26.9579),
-    "eigen":   (93.1689, 42.1872, 36.6564, 32.5680, 29.3228, 26.9552),
-    "tridiag": (93.1689, 41.9764, 37.0449, 32.9255, 29.7913, 27.4931),
+    "blkdiag": (90.6114, 40.4902, 33.7131, 30.1443, 27.5445, 25.4477),
+    "eigen":   (90.6114, 40.5124, 33.7020, 30.1372, 27.5289, 25.1180),
+    "tridiag": (90.6114, 40.4179, 33.9195, 29.9481, 27.3307, 24.9011),
 }
 REL_BAND = 0.07
 
@@ -114,7 +117,7 @@ def test_fused_stats_golden_trajectory(inv_mode):
 # so the trajectory is schedule-only — no is_ready wall-clock races.
 # ---------------------------------------------------------------------------
 
-GOLDEN_OVERLAP = (93.1689, 42.4726, 36.9508, 32.7847, 29.5379, 27.4448)
+GOLDEN_OVERLAP = (90.6114, 40.5608, 33.9000, 30.2302, 27.5309, 25.3084)
 
 
 @pytest.mark.slow
@@ -167,9 +170,9 @@ def test_overlap_golden_trajectory():
 # floor, so the band is wider than the autoencoder's and adds a small
 # absolute term: rel 15% + abs 0.02 per checkpoint.
 GOLDEN_CONV = {
-    "blkdiag": (1.3467, 0.9343, 0.0888, 0.0137, 0.0048, 0.0019),
-    "eigen":   (1.3467, 0.9342, 0.0887, 0.0137, 0.0048, 0.0019),
-    "tridiag": (1.3467, 0.9343, 0.0888, 0.0137, 0.0048, 0.0019),
+    "blkdiag": (1.3722, 0.9984, 0.1423, 0.0224, 0.0067, 0.0028),
+    "eigen":   (1.3722, 0.9985, 0.1423, 0.0224, 0.0067, 0.0028),
+    "tridiag": (1.3722, 0.9984, 0.1423, 0.0224, 0.0067, 0.0028),
 }
 REL_BAND_CONV = 0.15
 ABS_BAND_CONV = 0.02

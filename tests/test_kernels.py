@@ -217,8 +217,8 @@ def _paged_case(page, nb, b=4, hq=4, hkv=2, hd=32):
     straddle page boundaries (1, exactly one page, one past, mid-page)."""
     num_pages = 1 + b * nb
     q = _rand(40, (b, hq, hd), jnp.float32)
-    kp = _rand(41, (num_pages, page, hkv, hd), jnp.float32)
-    vp = _rand(42, (num_pages, page, hkv, hd), jnp.float32)
+    kp = _rand(41, (num_pages, hkv, page, hd), jnp.float32)
+    vp = _rand(42, (num_pages, hkv, page, hd), jnp.float32)
     pt = jax.random.permutation(jax.random.PRNGKey(43),
                                 jnp.arange(1, num_pages)).reshape(b, nb)
     lengths = jnp.asarray([1, page, page + 1,
@@ -232,17 +232,17 @@ def _paged_case(page, nb, b=4, hq=4, hkv=2, hd=32):
 def test_flash_decode_paged_parity(page, window, cap):
     """Block-indexed paged kernel (page table as scalar-prefetch operand)
     vs gather-the-pages-then-einsum, across page sizes, boundary-straddling
-    ragged lengths, sliding window, softcap and both GQA head blocks."""
+    ragged lengths, sliding window, softcap and both KV-head blocks."""
     from repro.kernels import ops
     from repro.kernels.flash_decode import flash_decode_paged
     q, kp, vp, pt, lengths = _paged_case(page, nb=4)
     kd, vd = ops.paged_gather(kp, vp, pt)
     want = ops.flash_decode_ref(q, kd, vd, lengths, window=window, cap=cap)
-    for bh in (1, 2):
-        out = flash_decode_paged(q, kp, vp, lengths, pt, bh=bh,
+    for hb in (1, 2):
+        out = flash_decode_paged(q, kp, vp, lengths, pt, hb=hb,
                                  window=window, cap=cap, interpret=True)
         np.testing.assert_allclose(out, want, rtol=2e-5, atol=2e-5,
-                                   err_msg=f"page={page} bh={bh}")
+                                   err_msg=f"page={page} hb={hb}")
 
 
 def test_flash_decode_paged_single_row_matches_batch():

@@ -324,16 +324,25 @@ def test_unsupported_arch_rejected():
 # paged decode route: block-indexed default vs the dense-gather oracle
 # ---------------------------------------------------------------------------
 
-def test_paged_route_is_default_and_matches_gather_oracle(smollm):
+@pytest.mark.parametrize("kernel", [False, True])
+def test_paged_route_is_default_and_matches_gather_oracle(smollm, kernel):
     """The block-indexed paged route (default) must be token-identical to
-    the dense-gather oracle route on the same request stream."""
+    the dense-gather oracle route on the same request stream — through the
+    einsum fallback and through the paged kernel (interpret mode here; the
+    route a TPU takes)."""
+    from repro.kernels import ops
     lm, params, cfg = smollm
     spec = [(0, 3, 4), (1, 6, 9), (2, 4, 2), (3, 8, 5), (4, 3, 7)]
 
-    eng = Engine(lm, params, batch_slots=3, max_len=32)
-    assert eng.decode_route == "paged"
-    paged = _reqs(cfg, spec)
-    eng.run(paged)
+    saved = dict(ops._STATE)
+    try:
+        ops.use_pallas(kernel, interpret=True if kernel else None)
+        eng = Engine(lm, params, batch_slots=3, max_len=32)
+        assert eng.decode_route == "paged"
+        paged = _reqs(cfg, spec)
+        eng.run(paged)
+    finally:
+        ops._STATE.update(saved)
     assert all(r.done for r in paged)
 
     ora = Engine(lm, params, batch_slots=3, max_len=32,
