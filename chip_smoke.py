@@ -47,6 +47,7 @@ import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 
 from repro.core.blocks import route_counts  # noqa: E402
+from repro.obs import CompileClock  # noqa: E402
 from repro.utils.compile_cache import enable_compile_cache  # noqa: E402
 
 ARCH = "smollm-135m"
@@ -65,29 +66,6 @@ def say(phase: str, msg: str) -> None:
 def custom_calls(compiled) -> int:
     """Mosaic kernels in a compiled program (0 = no Pallas kernel ran)."""
     return compiled.as_text().count('custom_call_target="tpu_custom_call"')
-
-
-class CompileClock:
-    """Seconds JAX spends tracing, lowering and compiling inside the
-    ``with`` block, from its own monitoring events."""
-
-    EVENTS = ("/jax/core/compile/jaxpr_trace_duration",
-              "/jax/core/compile/jaxpr_to_mlir_module_duration",
-              "/jax/core/compile/backend_compile_duration")
-
-    def __init__(self):
-        self.seconds = 0.0
-
-    def _on(self, event, duration, **_):
-        if event in self.EVENTS:
-            self.seconds += duration
-
-    def __enter__(self):
-        jax.monitoring.register_event_duration_secs_listener(self._on)
-        return self
-
-    def __exit__(self, *exc):
-        jax.monitoring.unregister_event_duration_listener(self._on)
 
 
 def _launch(argv):

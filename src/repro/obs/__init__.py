@@ -9,10 +9,10 @@ distributed refresh, serving):
 * :mod:`~repro.obs.tracing` — trace-safe spans (device work timed
   host-side after ``block_until_ready`` at span close, never via
   callbacks inside jit; optional ``jax.profiler.TraceAnnotation``
-  pass-through);
+  pass-through) and the compile listener :class:`CompileClock`;
 * :mod:`~repro.obs.export` — append-only schema-versioned JSONL event
-  sink, Prometheus text snapshot, console summarizer (the one formatting
-  path the launchers render from);
+  sink, console summarizer (the one formatting path the launchers
+  render from);
 * :mod:`~repro.obs.latency` — the shared TTFT / decode-gap definitions
   (live engine telemetry and ``bench_serving`` use the same class).
 
@@ -31,18 +31,18 @@ from __future__ import annotations
 from typing import Callable, Optional, Union
 
 from repro.obs.config import ObsConfig
-from repro.obs.export import (JsonlSink, console_summary, prometheus_text,
-                              read_jsonl, validate_event, SCHEMA_VERSION)
+from repro.obs.export import (JsonlSink, console_summary, read_jsonl,
+                              validate_event, SCHEMA_VERSION)
 from repro.obs.latency import RequestLatencyTracker
 from repro.obs.metrics import (Counter, Gauge, Histogram, Registry,
                                percentile)
-from repro.obs.tracing import NULL_SPAN, NullSpan, Span
+from repro.obs.tracing import NULL_SPAN, CompileClock, NullSpan, Span
 
 __all__ = [
     "Obs", "ObsConfig", "from_config",
     "Counter", "Gauge", "Histogram", "Registry", "percentile",
-    "Span", "NullSpan", "NULL_SPAN",
-    "JsonlSink", "console_summary", "prometheus_text", "read_jsonl",
+    "Span", "NullSpan", "NULL_SPAN", "CompileClock",
+    "JsonlSink", "console_summary", "read_jsonl",
     "validate_event", "SCHEMA_VERSION",
     "RequestLatencyTracker",
 ]
@@ -103,9 +103,6 @@ class Obs:
 
     def summary(self, title: str = "obs") -> str:
         return console_summary(self.registry, title)
-
-    def prometheus(self) -> str:
-        return prometheus_text(self.registry)
 
     def close(self) -> None:
         if self.sink is not None:

@@ -1,4 +1,4 @@
-"""Exporters: JSONL event sink, Prometheus text snapshot, console summary.
+"""Exporters: JSONL event sink and console summary.
 
 JSONL event schema (version ``SCHEMA_VERSION``)
 -----------------------------------------------
@@ -9,7 +9,8 @@ One JSON object per line.  Every event carries::
 Known event types and their required fields (``EVENT_FIELDS``):
 
 * ``train_step``    — ``step``, ``loss``, ``wall_s`` (+ lam/gamma/alpha/
-  rho/nu/staleness/rejected/fused_stats when applicable)
+  rho/nu/staleness/rejected/fused_stats when applicable, and the step's
+  host_syncs and compiles)
 * ``kfac_step``     — ``step``, ``stages`` ({stage name: seconds})
 * ``refresh``       — ``mode``, ``wall_s`` (+ plan cost / shard info /
   forced / cancelled for the distributed modes)
@@ -36,7 +37,7 @@ import threading
 import time
 from typing import Dict, Optional
 
-from repro.obs.metrics import Histogram, Registry
+from repro.obs.metrics import Registry
 
 SCHEMA_VERSION = 1
 
@@ -129,50 +130,6 @@ def read_jsonl(path: str) -> list:
             except ValueError as e:
                 raise ValueError(f"{path}:{i}: {e}") from e
     return out
-
-
-# ---------------------------------------------------------------------------
-# Prometheus text snapshot
-# ---------------------------------------------------------------------------
-
-def _prom_name(name: str) -> str:
-    out = "".join(c if c.isalnum() or c == "_" else "_" for c in name)
-    return f"repro_{out}"
-
-
-def _prom_labels(labels) -> str:
-    if not labels:
-        return ""
-    inner = ",".join(f'{k}="{v}"' for k, v in labels)
-    return "{" + inner + "}"
-
-
-def prometheus_text(registry: Registry) -> str:
-    """Prometheus exposition-format snapshot of the whole registry.
-    Histograms export as summary-style count/sum plus p50/p99 gauges
-    (quantiles over the bounded reservoir)."""
-    lines = []
-    seen_types = set()
-    for m in registry.metrics():
-        pname = _prom_name(m.name)
-        labs = _prom_labels(m.labels)
-        if isinstance(m, Histogram):
-            if pname not in seen_types:
-                lines.append(f"# TYPE {pname} summary")
-                seen_types.add(pname)
-            snap = m.snapshot()
-            lines.append(f"{pname}_count{labs} {snap['count']}")
-            lines.append(f"{pname}_sum{labs} {snap['sum']}")
-            for q, key in ((0.5, "p50"), (0.99, "p99")):
-                if key in snap:
-                    qlabs = list(m.labels) + [("quantile", str(q))]
-                    lines.append(f"{pname}{_prom_labels(qlabs)} {snap[key]}")
-        else:
-            if pname not in seen_types:
-                lines.append(f"# TYPE {pname} {m.kind}")
-                seen_types.add(pname)
-            lines.append(f"{pname}{labs} {m.value}")
-    return "\n".join(lines) + ("\n" if lines else "")
 
 
 # ---------------------------------------------------------------------------

@@ -169,9 +169,8 @@ class Registry:
         self._metrics: Dict[Tuple[str, LabelKey], Metric] = {}
 
     def _get(self, cls, name: str, labels, **kw) -> Metric:
-        # keyed by (name, labels) — one name maps to ONE kind, as the
-        # Prometheus exposition format requires; asking for the same name
-        # as a different kind is a bug, not a new instrument
+        # keyed by (name, labels) — one name maps to ONE kind; asking for
+        # the same name as a different kind is a bug, not a new instrument
         key = (name, _label_key(labels))
         with self._lock:
             m = self._metrics.get(key)

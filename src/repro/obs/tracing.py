@@ -15,7 +15,12 @@ differ).
 the callable form when the arrays are produced inside the ``with``
 body).  With ``ObsConfig.trace_annotations`` the span also enters a
 ``jax.profiler.TraceAnnotation``, so the same names line up in
-TensorBoard / perfetto device profiles.
+TensorBoard / perfetto device profiles.  A span with no ``block`` adds
+no sync: it times whatever sync its body already makes.
+
+:class:`CompileClock` is the program's one listener for JAX's compile
+events: seconds spent tracing, lowering and compiling, and the count of
+backend compiles, inside its ``with`` block.
 """
 from __future__ import annotations
 
@@ -75,3 +80,35 @@ class NullSpan:
 
 
 NULL_SPAN = NullSpan()
+
+
+class CompileClock:
+    """Seconds JAX spends tracing, lowering and compiling inside the
+    ``with`` block, and the backend compiles among them, from its own
+    monitoring events.  The listener is registered on enter and always
+    removed on exit, an exception included."""
+
+    EVENTS = ("/jax/core/compile/jaxpr_trace_duration",
+              "/jax/core/compile/jaxpr_to_mlir_module_duration",
+              "/jax/core/compile/backend_compile_duration")
+    COMPILE = "/jax/core/compile/backend_compile_duration"
+
+    def __init__(self):
+        self.seconds = 0.0
+        self.compiles = 0
+
+    def _on(self, event, duration, **_):
+        if event in self.EVENTS:
+            self.seconds += duration
+        if event == self.COMPILE:
+            self.compiles += 1
+
+    def __enter__(self):
+        import jax
+        jax.monitoring.register_event_duration_secs_listener(self._on)
+        return self
+
+    def __exit__(self, *exc):
+        import jax
+        jax.monitoring.unregister_event_duration_listener(self._on)
+        return False

@@ -666,6 +666,14 @@ class Stage(NamedTuple):
     run: Callable[[StepContext], None]
 
 
+def _jit_named(name: str, fn: Callable) -> Callable:
+    """``jax.jit`` of ``fn`` under ``name``, so that its module reads
+    ``jit_<name>`` in a device trace or compile log, not ``jit__lambda``;
+    the program is otherwise the one ``jax.jit(fn)`` builds."""
+    fn.__name__ = fn.__qualname__ = name
+    return jax.jit(fn)
+
+
 class KFACPipeline:
     """Drives one optimizer step as an ordered list of named stages.
 
@@ -690,9 +698,11 @@ class KFACPipeline:
         self._stats = jax.jit(eng.stats_grads)
         self._grads_only = jax.jit(eng.grads_only)
         self._rescale = jax.jit(eng.rescale_step) if eng.eigen else None
-        self._refresh = jax.jit(lambda s: eng.refresh_inverses(s, hot=True))
+        self._refresh = _jit_named(
+            "kfac_refresh", lambda s: eng.refresh_inverses(s, hot=True))
         self._refresh_sub = {
-            i: jax.jit(lambda s, ns=tuple(g): eng.refresh_subset(s, ns))
+            i: _jit_named("kfac_refresh_group",
+                          lambda s, ns=tuple(g): eng.refresh_subset(s, ns))
             for i, g in enumerate(eng.stagger_groups())} \
             if eng.refresh_mode == "staggered" else None
         # distributed curvature service (repro.distributed): the sharded
@@ -709,9 +719,11 @@ class KFACPipeline:
                     deterministic=cfg.overlap_deterministic, obs=self.obs)
         self._multi = jax.jit(eng.refresh_multi)
         if cfg.use_rescale:
-            self._update = jax.jit(
+            self._update = _jit_named(
+                "kfac_update",
                 lambda s, p, g, b, r: eng.apply_update(s, p, g, b, r))
-            self._update3 = jax.jit(
+            self._update3 = _jit_named(
+                "kfac_update3",
                 lambda s, p, g, b, r, gs, i3: eng.apply_update(
                     s, p, g, b, r,
                     cand_inv=[jax.tree.map(lambda x: x[c], i3)
@@ -726,9 +738,11 @@ class KFACPipeline:
             # fixed-lr path: precondition + momentum + global-norm clip as
             # one fused stage (docs/optimizer_api.md "stage map"); on T2
             # steps the gamma sweep keeps candidate 0 (legacy c_star=0)
-            self._update = jax.jit(
+            self._update = _jit_named(
+                "kfac_update",
                 lambda s, p, g, b, r: eng.apply_update_fused(s, p, g, b, r))
-            self._update3 = jax.jit(
+            self._update3 = _jit_named(
+                "kfac_update3",
                 lambda s, p, g, b, r, gs, i3: eng.apply_update_fused(
                     s, p, g, b, r,
                     inv_override=jax.tree.map(lambda x: x[0], i3),
@@ -736,6 +750,11 @@ class KFACPipeline:
             update_stage = Stage("fused_precondition_momentum_clip",
                                  self._stage_quadratic)
         self._lambda = jax.jit(eng.lambda_step)
+        # device->host reads at the pipeline's two sync sites (always live)
+        self._c_read_step = self.obs.counter("train/host_syncs",
+                                             {"site": "kfac/read_step"})
+        self._c_lambda_guard = self.obs.counter("train/host_syncs",
+                                                {"site": "kfac/lambda_guard"})
         self.stages = [
             Stage("estimate_stats", self._stage_estimate_stats),
             Stage("scheduled_inverse_refresh", self._stage_refresh),
@@ -848,8 +867,10 @@ class KFACPipeline:
             # a non-finite update will be rejected by the trainer: evaluate
             # rho at the params it will actually keep, as the pre-redesign
             # trainer (guard before lambda_step) did
-            target = (ctx.new_params if bool(T.tree_isfinite(ctx.new_params))
-                      else ctx.params)
+            with self.obs.span("kfac/lambda_guard"):
+                finite = bool(T.tree_isfinite(ctx.new_params))
+            self._c_lambda_guard.inc()
+            target = ctx.new_params if finite else ctx.params
             ctx.state, rho = self._lambda(ctx.state, target,
                                           ctx.batch, ctx.rng)
             ctx.metrics["rho"] = rho
@@ -869,7 +890,9 @@ class KFACPipeline:
         return state
 
     def update(self, grads, state: KFACState, params, batch, rng):
-        step = int(state.step)        # schedule off the state, not a loop var
+        with self.obs.span("kfac/read_step"):
+            step = int(state.step)    # schedule off the state, not a loop var
+        self._c_read_step.inc()
         if self._start is None:
             self._start = step
         ctx = StepContext(step=step, warmup=step - self._start < 3,

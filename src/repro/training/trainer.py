@@ -20,6 +20,7 @@ Fault tolerance:
 """
 from __future__ import annotations
 
+import contextlib
 import signal
 import time
 from typing import Any, Dict, Optional
@@ -29,6 +30,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import TrainConfig
+from repro.obs import CompileClock
 from repro.optimizers import as_optimizer
 from repro.training.checkpoint import Checkpointer
 from repro.utils import tree as T
@@ -51,6 +53,12 @@ class Trainer:
                                        else train_cfg.obs)
         self._c_rejected = self.obs.counter("train/rejected_steps")
         self._c_steps = self.obs.counter("train/steps")
+        # device->host reads at the loop's sync sites (always live); the
+        # optimizer counts its own sites under the same name
+        self._c_finite_syncs = self.obs.counter(
+            "train/host_syncs", {"site": "train/finite_check"})
+        self._c_metric_syncs = self.obs.counter(
+            "train/host_syncs", {"site": "train/metrics_to_host"})
         self._preempted = False
         self._bundle_writer = None
         self._install_handlers()
@@ -83,61 +91,86 @@ class Trainer:
         t_start = time.time()
         fused = bool(getattr(getattr(self.opt, "engine", None),
                              "fused", False))
-        for step in range(start_step, steps):
-            batch = data.batch(step)
-            rng = jax.random.fold_in(jax.random.PRNGKey(self.tc.seed), step)
+        # compile events are counted per step only when enabled; the
+        # listener is removed when the loop ends, by an exception too
+        clock = (CompileClock() if self.obs.enabled
+                 else contextlib.nullcontext())
+        with clock:
+            for step in range(start_step, steps):
+                if self.obs.enabled:
+                    syncs0, compiles0 = self._host_syncs(), clock.compiles
+                with self.obs.span("train/step_inputs"):
+                    batch = data.batch(step)
+                    rng = jax.random.fold_in(
+                        jax.random.PRNGKey(self.tc.seed), step)
 
-            # per-step wall time: host-side span blocking on the produced
-            # params at close (enabled only — disabled is the shared no-op
-            # span: no clock reads, no extra sync, same jitted programs)
-            with self.obs.span("train/step",
-                               block=lambda: new_params) as span:
-                new_params, state, metrics = self.opt.update(
-                    None, state, params, batch, rng)
+                # per-step wall time: host-side span blocking on the
+                # produced params at close (enabled only — disabled is the
+                # shared no-op span: no clock reads, no extra sync, same
+                # jitted programs)
+                with self.obs.span("train/step",
+                                   block=lambda: new_params) as span:
+                    new_params, state, metrics = self.opt.update(
+                        None, state, params, batch, rng)
 
-            # non-finite guard: skip poisoned updates, let the optimizer
-            # react (K-FAC: 4x damping + momentum reset)
-            finite = bool(T.tree_isfinite(new_params)) and np.isfinite(
-                float(metrics.get("delta_norm", 0.0)))
-            self._c_steps.inc()
-            if finite:
-                params = new_params
-            else:
-                state = self.opt.reject(state)
-                self._c_rejected.inc()
-                log(f"[trainer] step {step}: non-finite update SKIPPED "
-                    f"(rejected by {self.opt.name})")
+                # non-finite guard: skip poisoned updates, let the
+                # optimizer react (K-FAC: 4x damping + momentum reset)
+                with self.obs.span("train/finite_check"):
+                    reads = 1
+                    finite = bool(T.tree_isfinite(new_params))
+                    if finite:
+                        delta = metrics.get("delta_norm", 0.0)
+                        reads += isinstance(delta, jax.Array)
+                        finite = bool(np.isfinite(float(delta)))
+                self._c_finite_syncs.inc(reads)
+                self._c_steps.inc()
+                if finite:
+                    params = new_params
+                else:
+                    state = self.opt.reject(state)
+                    self._c_rejected.inc()
+                    log(f"[trainer] step {step}: non-finite update SKIPPED "
+                        f"(rejected by {self.opt.name})")
 
-            # swap hook: optimizers running asynchronous side computations
-            # (K-FAC refresh_mode="overlap") commit any finished buffer
-            # here without blocking the step loop
-            if self.opt.poll is not None:
-                state = self.opt.poll(state)
+                # swap hook: optimizers running asynchronous side
+                # computations (K-FAC refresh_mode="overlap") commit any
+                # finished buffer here without blocking the step loop
+                if self.opt.poll is not None:
+                    state = self.opt.poll(state)
 
-            history.append({k: float(v) for k, v in metrics.items()
-                            if jnp.ndim(v) == 0})
-            if self.obs.enabled:
-                self._emit_step(step, span.seconds, history[-1],
-                                rejected=not finite, fused=fused)
-            if step % self.tc.log_every == 0:
-                extras = " ".join(
-                    f"{k}={history[-1][k]:.2e}" for k in ("alpha", "lam")
-                    if k in history[-1])
-                log(f"[trainer] step {step}: "
-                    f"loss={history[-1]['loss']:.4f} {extras}".rstrip())
+                with self.obs.span("train/metrics_to_host"):
+                    scalars = [(k, v) for k, v in metrics.items()
+                               if jnp.ndim(v) == 0]
+                    history.append({k: float(v) for k, v in scalars})
+                self._c_metric_syncs.inc(
+                    sum(isinstance(v, jax.Array) for _, v in scalars))
+                if self.obs.enabled:
+                    with self.obs.span("train/emit"):
+                        self._emit_step(step, span.seconds, history[-1],
+                                        rejected=not finite, fused=fused,
+                                        host_syncs=self._host_syncs()
+                                        - syncs0,
+                                        compiles=clock.compiles - compiles0)
+                if step % self.tc.log_every == 0:
+                    extras = " ".join(
+                        f"{k}={history[-1][k]:.2e}" for k in ("alpha", "lam")
+                        if k in history[-1])
+                    log(f"[trainer] step {step}: "
+                        f"loss={history[-1]['loss']:.4f} {extras}".rstrip())
 
-            if self.ckpt is not None and (
-                    (step + 1) % self.tc.checkpoint_every == 0):
-                bundle_ref = self._export_bundle(step + 1, state, log)
-                self.ckpt.save(step + 1, {"params": params, "state": state},
-                               curvature_bundle=bundle_ref)
+                if self.ckpt is not None and (
+                        (step + 1) % self.tc.checkpoint_every == 0):
+                    bundle_ref = self._export_bundle(step + 1, state, log)
+                    self.ckpt.save(step + 1,
+                                   {"params": params, "state": state},
+                                   curvature_bundle=bundle_ref)
 
-            if self._preempted:
-                log(f"[trainer] preempted at step {step}; checkpointing")
-                if self.ckpt is not None:
-                    self.ckpt.save(step + 1, {"params": params,
-                                              "state": state}, block=True)
-                break
+                if self._preempted:
+                    log(f"[trainer] preempted at step {step}; checkpointing")
+                    if self.ckpt is not None:
+                        self.ckpt.save(step + 1, {"params": params,
+                                                  "state": state}, block=True)
+                    break
 
         if self.ckpt is not None:
             self.ckpt.wait()
@@ -147,11 +180,18 @@ class Trainer:
                 "seconds": time.time() - t_start}
 
     # ------------------------------------------------------------------
+    def _host_syncs(self) -> float:
+        """Device->host reads made so far at every sync site."""
+        return sum(c.value for c in self.obs.registry.find(
+            "train/host_syncs"))
+
     def _emit_step(self, step: int, wall_s, hist_row: dict, *,
-                   rejected: bool, fused: bool):
+                   rejected: bool, fused: bool, host_syncs: float,
+                   compiles: int):
         """One ``train_step`` JSONL event + gauges (enabled path only).
         The optimizer's scalar metrics ride along under their own names
-        (lam / gamma / alpha / rho / nu / staleness when present)."""
+        (lam / gamma / alpha / rho / nu / staleness when present), with
+        the step's device->host reads and backend compiles."""
         def fin(x):      # a rejected step's metrics may be NaN/Inf; the
             return float(x) if np.isfinite(x) else None   # schema is finite-only
         extras = {k: fin(hist_row[k])
@@ -161,7 +201,9 @@ class Trainer:
         self.obs.emit("train_step", step=step,
                       loss=fin(hist_row.get("loss", 0.0)),
                       wall_s=wall_s, rejected=rejected,
-                      fused_stats=fused, **extras)
+                      fused_stats=fused, host_syncs=int(host_syncs),
+                      compiles=compiles, **extras)
+        self.obs.counter("train/compiles").inc(compiles)
         self.obs.gauge("train/loss").set(hist_row.get("loss", 0.0))
         if "lam" in hist_row:
             self.obs.gauge("train/lambda").set(hist_row["lam"])

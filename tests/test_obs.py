@@ -33,8 +33,8 @@ from repro import optimizers
 from repro.configs.base import KFACConfig, TrainConfig
 from repro.data.pipeline import SyntheticAutoencoderData
 from repro.models.mlp import MLP
-from repro.obs import (Obs, ObsConfig, Registry, RequestLatencyTracker,
-                       console_summary, percentile, prometheus_text,
+from repro.obs import (NULL_SPAN, Obs, ObsConfig, Registry,
+                       RequestLatencyTracker, console_summary, percentile,
                        read_jsonl, validate_event)
 from repro.obs.export import JsonlSink
 from repro.training.trainer import Trainer
@@ -96,7 +96,7 @@ def test_registry_labels_and_kind_clash():
 
 
 # ---------------------------------------------------------------------------
-# exporters: JSONL schema, prometheus, console
+# exporters: JSONL schema, console
 # ---------------------------------------------------------------------------
 
 def test_jsonl_schema_roundtrip(tmp_path):
@@ -144,22 +144,22 @@ def test_jsonl_rejects_bad_events(tmp_path):
         read_jsonl(path)
 
 
-def test_prometheus_and_console_render():
+def test_console_render():
     reg = Registry()
     reg.counter("serve/steps").inc(5)
+    reg.counter("train/host_syncs", {"site": "kfac/read_step"}).inc(3)
     reg.gauge("train/loss", {"arch": "mlp"}).set(1.25)
     h = reg.histogram("span_s", {"span": "kfac/estimate_stats"})
     for v in (0.1, 0.2, 0.3):
         h.observe(v)
-    prom = prometheus_text(reg)
-    assert "# TYPE repro_serve_steps counter" in prom
-    assert "repro_serve_steps 5" in prom
-    assert 'repro_train_loss{arch="mlp"} 1.25' in prom
-    assert 'repro_span_s_count{span="kfac/estimate_stats"} 3' in prom
-    assert 'quantile="0.5"' in prom
+    reg.histogram("span_s", {"span": "never/observed"})
     text = console_summary(reg, title="t")
     assert "[t] serve/steps = 5" in text
-    assert "span_s{span=kfac/estimate_stats}" in text and "p99" in text
+    assert "[t] train/host_syncs{site=kfac/read_step} = 3" in text
+    assert "[t] train/loss{arch=mlp} = 1.25" in text
+    assert "span_s{span=kfac/estimate_stats}: n=3 mean=0.2" in text
+    assert "p50=0.2" in text and "p99" in text
+    assert "never/observed" not in text
 
 
 # ---------------------------------------------------------------------------
@@ -175,12 +175,19 @@ def _jit_cache_sizes(pipe):
     return out
 
 
+def _duration_listeners():
+    from jax._src import monitoring
+    return list(monitoring.get_event_duration_listeners())
+
+
 def test_training_disabled_bitwise_parity(tmp_path):
     """Enabled-vs-disabled training is bitwise identical (params AND the
     full scalar history) and compiles the same number of programs per
-    stage — telemetry must never touch the jitted computation."""
+    stage — telemetry must never touch the jitted computation — and
+    ``fit`` leaves no compile listener registered behind it."""
     steps = 8
     results, cache_sizes = [], []
+    listeners = _duration_listeners()
     for enabled in (False, True):
         mlp, params, data = _problem()
         ocfg = ObsConfig(enabled=enabled,
@@ -195,6 +202,7 @@ def test_training_disabled_bitwise_parity(tmp_path):
                      obs=obs)
         out = tr.fit(params, data, steps, log=lambda *_: None)
         obs.close()
+        assert _duration_listeners() == listeners
         results.append(out)
         # the Optimizer wraps the pipeline's bound methods
         pipe = opt.update.__self__
@@ -230,6 +238,189 @@ def test_trainer_counts_rejected_steps():
     tr.fit(bad, data, 2, log=lambda *_: None)
     assert obs.registry.counter("train/rejected_steps").value >= 1
     assert obs.registry.counter("train/steps").value >= 2
+
+
+# ---------------------------------------------------------------------------
+# the loop's sync sites: spans, read counts, compiles, named programs
+# ---------------------------------------------------------------------------
+
+T1 = 2
+SITES = ("train/step_inputs", "kfac/read_step", "train/finite_check",
+         "train/metrics_to_host", "kfac/lambda_guard", "train/emit")
+
+
+def _kfac_fit(steps, ocfg, **kw):
+    """A tiny K-FAC fit (λ rule every T1, γ sweep every 4, refresh every
+    3); returns (fit result, Obs, pipeline)."""
+    mlp, params, data = _problem()
+    cfg = KFACConfig(lambda_init=3.0, t1=T1, t2=4, t3=3, eta=1e-5, obs=ocfg,
+                     **kw)
+    obs = Obs(ocfg)
+    opt = optimizers.kfac(mlp, cfg, family="bernoulli", obs=obs)
+    tr = Trainer(mlp, opt, TrainConfig(steps=steps, seed=0,
+                                       log_every=10 ** 9, obs=ocfg), obs=obs)
+    out = tr.fit(params, data, steps, log=lambda *_: None)
+    obs.close()
+    return out, obs, opt.update.__self__
+
+
+def _lambda_steps(steps):
+    return [k for k in range(steps) if (k + 1) % T1 == 0]
+
+
+@pytest.fixture
+def reads(monkeypatch):
+    """Counts device->host conversions of jax arrays (``bool``, ``int``,
+    ``float``, ``__index__``, ``__array__``, ``item``) and calls of
+    ``jax.block_until_ready``."""
+    from jax._src.array import ArrayImpl
+    n = {"read": 0, "block": 0}
+
+    def counted(fn, key):
+        def f(*a, **k):
+            n[key] += 1
+            return fn(*a, **k)
+        return f
+
+    for name in ("__bool__", "__int__", "__float__", "__index__",
+                 "__array__", "item"):
+        monkeypatch.setattr(ArrayImpl, name,
+                            counted(getattr(ArrayImpl, name), "read"))
+    monkeypatch.setattr(jax, "block_until_ready",
+                        counted(jax.block_until_ready, "block"))
+    return n
+
+
+def test_site_spans_in_profiler_trace(tmp_path):
+    """Under the profiler every site span lands in the host plane once a
+    step; ``kfac/lambda_guard`` only on T1 steps, inside its stage's
+    ``kfac/adapt_lambda`` span."""
+    import glob
+    steps = 5
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        _kfac_fit(steps, ObsConfig(enabled=True, trace_annotations=True))
+    finally:
+        jax.profiler.stop_trace()
+    path, = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    spans = {}
+    for plane in jax.profiler.ProfileData.from_file(path).planes:
+        if plane.name == "/host:CPU":
+            for line in plane.lines:
+                for e in line.events:
+                    spans.setdefault(e.name, []).append(
+                        (e.start_ns, e.start_ns + e.duration_ns))
+    for site in SITES:
+        want = len(_lambda_steps(steps)) if site == "kfac/lambda_guard" \
+            else steps
+        assert len(spans.get(site, [])) == want, site
+    stage = spans["kfac/adapt_lambda"]
+    for s, e in spans["kfac/lambda_guard"]:
+        assert any(a <= s and e <= b for a, b in stage)
+
+
+def test_site_spans_add_no_block_or_read(reads, monkeypatch):
+    """The new spans carry no ``block``: with them live, the enabled fit
+    makes the same ``block_until_ready`` calls and device reads as with
+    them replaced by the no-op span."""
+    counts = []
+    for live in (True, False):
+        if not live:
+            span = Obs.span
+            monkeypatch.setattr(
+                Obs, "span", lambda self, name, block=None:
+                NULL_SPAN if name in SITES else span(self, name, block))
+        reads.update(read=0, block=0)
+        _kfac_fit(6, ObsConfig(enabled=True))
+        counts.append(dict(reads))
+    assert counts[0] == counts[1]
+    assert counts[0]["block"] > 0 and counts[0]["read"] > 0
+
+
+def test_host_syncs_count_the_schedules_reads(reads):
+    """``train/host_syncs{site}`` is live with obs disabled and counts
+    each site's reads: one step read per step, the finite check's two,
+    one per scalar metric, the λ guard on T1 steps — every device read
+    the loop makes."""
+    steps = 6
+    out, obs, _ = _kfac_fit(steps, ObsConfig())
+    got = {m.labels[0][1]: m.value
+           for m in obs.registry.find("train/host_syncs")}
+    assert got == {
+        "kfac/read_step": steps,
+        "train/finite_check": 2 * steps,
+        "train/metrics_to_host": sum(len(h) for h in out["history"]),
+        "kfac/lambda_guard": len(_lambda_steps(steps))}
+    assert sum(got.values()) == reads["read"]
+
+
+def test_train_step_events_carry_syncs_and_compiles(tmp_path):
+    """Each ``train_step`` event carries the step's reads and backend
+    compiles: compiles on the first step, none once every program of the
+    period (refresh, λ step, γ sweep) has run."""
+    steps = 9
+    path = str(tmp_path / "train.jsonl")
+    out, obs, _ = _kfac_fit(steps, ObsConfig(enabled=True, jsonl_path=path))
+    ev = [e for e in read_jsonl(path) if e["event"] == "train_step"]
+    assert [e["step"] for e in ev] == list(range(steps))
+    lam = _lambda_steps(steps)
+    assert [e["host_syncs"] for e in ev] == [
+        1 + 2 + len(h) + (k in lam) for k, h in enumerate(out["history"])]
+    assert ev[0]["compiles"] > 0
+    assert all(e["compiles"] == 0 for e in ev[5:])
+    assert obs.registry.counter("train/compiles").value == sum(
+        e["compiles"] for e in ev)
+
+
+def test_fit_unregisters_compile_listener_on_error():
+    class Broken:
+        def __init__(self, data):
+            self.data = data
+
+        def batch(self, step):
+            if step == 2:
+                raise RuntimeError("input pipeline failed")
+            return self.data.batch(step)
+
+    mlp, params, data = _problem()
+    ocfg = ObsConfig(enabled=True)
+    obs = Obs(ocfg)
+    opt = optimizers.kfac(mlp, KFACConfig(lambda_init=3.0, obs=ocfg),
+                          family="bernoulli", obs=obs)
+    tr = Trainer(mlp, opt, TrainConfig(steps=4, seed=0, log_every=10 ** 9,
+                                       obs=ocfg), obs=obs)
+    listeners = _duration_listeners()
+    with pytest.raises(RuntimeError, match="input pipeline"):
+        tr.fit(params, Broken(data), 4, log=lambda *_: None)
+    assert _duration_listeners() == listeners
+
+
+@pytest.mark.parametrize("kw", [
+    {},
+    {"inv_mode": "eigen", "refresh_mode": "staggered"},
+    {"use_rescale": False},
+], ids=["blkdiag", "eigen-staggered", "fixed-lr"])
+def test_stage_programs_are_named(kw):
+    """No stage program of the pipeline is a ``<lambda>``: each module
+    reads ``jit_<name>`` in a trace or compile log."""
+    mlp, params, data = _problem()
+    opt = optimizers.kfac(mlp, KFACConfig(lambda_init=3.0, t3=3, **kw),
+                          family="bernoulli")
+    pipe = opt.update.__self__
+    jitted = []
+    for v in vars(pipe).values():
+        for f in (v.values() if isinstance(v, dict) else [v]):
+            if hasattr(f, "lower"):
+                jitted.append(f)
+    names = {f.__name__ for f in jitted}
+    assert "<lambda>" not in names
+    assert {"kfac_refresh", "kfac_update", "kfac_update3"} <= names
+    if "refresh_mode" in kw:
+        assert "kfac_refresh_group" in names
+    state = opt.init(params, data.batch(0))
+    assert "@jit_kfac_refresh" in pipe._refresh.lower(state).as_text()
 
 
 # ---------------------------------------------------------------------------
