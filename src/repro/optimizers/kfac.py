@@ -22,6 +22,9 @@ Two layers:
                             (S6.4/S7) and candidate selection by M(delta).
       ``lambda_step``       every T1 steps: reduction ratio rho + LM rule
                             (S6.5).
+      ``guard``             ends every update program: a non-finite
+                            update keeps the old params and ``reject``'s
+                            λ and momentum, flagged in its metrics.
 
     Keeping the stages separate (no lax.cond megakernel) keeps the
     per-step HLO — and hence the roofline accounting — honest; the dry-run
@@ -640,6 +643,44 @@ class KFACEngine:
         lam = D.lambda_update(state.lam, rho, self._omega1())
         return state.replace(lam=lam), rho
 
+    # ------------------------------------------------------------------
+    # non-finite guard, on the device
+    # ------------------------------------------------------------------
+    def reject(self, state: KFACState) -> KFACState:
+        """A non-finite update was skipped: raise damping, drop momentum."""
+        return state.replace(lam=state.lam * 4.0,
+                             delta0=T.tree_zeros_like(state.delta0))
+
+    def guard(self, params, new_params, state: KFACState, metrics):
+        """Non-finite guard, traced into the program that writes the
+        update: ``finite`` is one reduction over the new params and the
+        update's norm; a non-finite update keeps the old params and the
+        rejected λ and momentum (:meth:`reject`).  Only those leaves are
+        selected; the rest of the state is the update's.
+        ``metrics["finite"]`` carries the flag."""
+        finite = (T.tree_isfinite(new_params)
+                  & jnp.isfinite(metrics["delta_norm"]))
+
+        def keep(new, old):
+            return jax.tree.map(lambda n, o: jnp.where(finite, n, o),
+                                new, old)
+
+        rejected = self.reject(state)
+        state = state.replace(lam=keep(state.lam, rejected.lam),
+                              delta0=keep(state.delta0, rejected.delta0))
+        return keep(new_params, params), state, dict(metrics, finite=finite)
+
+    def guarded_lambda_step(self, state: KFACState, new_params, batch, rng,
+                            finite, lam):
+        """``lambda_step`` after a guarded update: the rule runs from the λ
+        the update saw (``lam``) at the params the guard kept, and a
+        rejected update's :meth:`reject` comes after it (the rule clips,
+        so the order matters)."""
+        state, rho = self.lambda_step(state.replace(lam=lam), new_params,
+                                      batch, rng)
+        lam = jnp.where(finite, state.lam, self.reject(state).lam)
+        return state.replace(lam=lam), rho
+
 
 # ---------------------------------------------------------------------------
 # the pipeline: stages + schedule -> Optimizer(init, update, reject)
@@ -718,17 +759,20 @@ class KFACPipeline:
                     self._refresh_sharded, bound=max(1, cfg.t3),
                     deterministic=cfg.overlap_deterministic, obs=self.obs)
         self._multi = jax.jit(eng.refresh_multi)
+        # every update program ends in the non-finite guard
+        # (KFACEngine.guard): its metrics carry the device flag "finite"
         if cfg.use_rescale:
             self._update = _jit_named(
                 "kfac_update",
-                lambda s, p, g, b, r: eng.apply_update(s, p, g, b, r))
+                lambda s, p, g, b, r: eng.guard(
+                    p, *eng.apply_update(s, p, g, b, r)))
             self._update3 = _jit_named(
                 "kfac_update3",
-                lambda s, p, g, b, r, gs, i3: eng.apply_update(
+                lambda s, p, g, b, r, gs, i3: eng.guard(p, *eng.apply_update(
                     s, p, g, b, r,
                     cand_inv=[jax.tree.map(lambda x: x[c], i3)
                               for c in range(3)],
-                    gammas=gs))
+                    gammas=gs)))
             # precondition is fused into the quadratic-model stage: the
             # M(delta) solve needs every candidate's preconditioned delta
             # and the exact-F products in one HLO (S6.4/S6.6)
@@ -740,21 +784,27 @@ class KFACPipeline:
             # steps the gamma sweep keeps candidate 0 (legacy c_star=0)
             self._update = _jit_named(
                 "kfac_update",
-                lambda s, p, g, b, r: eng.apply_update_fused(s, p, g, b, r))
+                lambda s, p, g, b, r: eng.guard(
+                    p, *eng.apply_update_fused(s, p, g, b, r)))
             self._update3 = _jit_named(
                 "kfac_update3",
-                lambda s, p, g, b, r, gs, i3: eng.apply_update_fused(
-                    s, p, g, b, r,
-                    inv_override=jax.tree.map(lambda x: x[0], i3),
-                    gamma_override=gs[0]))
+                lambda s, p, g, b, r, gs, i3: eng.guard(
+                    p, *eng.apply_update_fused(
+                        s, p, g, b, r,
+                        inv_override=jax.tree.map(lambda x: x[0], i3),
+                        gamma_override=gs[0])))
             update_stage = Stage("fused_precondition_momentum_clip",
                                  self._stage_quadratic)
-        self._lambda = jax.jit(eng.lambda_step)
-        # device->host reads at the pipeline's two sync sites (always live)
+        self._lambda = _jit_named(
+            "lambda_step", lambda s, p, b, r, f, lam: eng.guarded_lambda_step(
+                s, p, b, r, f, lam))
+        # the step counter is read from the state once per run (the
+        # pipeline's one sync site, always counted) and then counted here:
+        # _step is the step of the state whose step leaf is _step_leaf
         self._c_read_step = self.obs.counter("train/host_syncs",
                                              {"site": "kfac/read_step"})
-        self._c_lambda_guard = self.obs.counter("train/host_syncs",
-                                                {"site": "kfac/lambda_guard"})
+        self._step: Optional[int] = None
+        self._step_leaf = None
         self.stages = [
             Stage("estimate_stats", self._stage_estimate_stats),
             Stage("scheduled_inverse_refresh", self._stage_refresh),
@@ -864,20 +914,18 @@ class KFACPipeline:
     def _stage_adapt_lambda(self, ctx: StepContext):
         cfg = self.engine.cfg
         if cfg.t1 > 0 and (ctx.step + 1) % cfg.t1 == 0:
-            # a non-finite update will be rejected by the trainer: evaluate
-            # rho at the params it will actually keep, as the pre-redesign
-            # trainer (guard before lambda_step) did
-            with self.obs.span("kfac/lambda_guard"):
-                finite = bool(T.tree_isfinite(ctx.new_params))
-            self._c_lambda_guard.inc()
-            target = ctx.new_params if finite else ctx.params
-            ctx.state, rho = self._lambda(ctx.state, target,
-                                          ctx.batch, ctx.rng)
+            # rho at the params the update's guard kept (the old ones after
+            # a non-finite update); the rule starts from the λ the update
+            # saw (its metric), before the guard's reject
+            ctx.state, rho = self._lambda(
+                ctx.state, ctx.new_params, ctx.batch, ctx.rng,
+                ctx.metrics["finite"], ctx.metrics["lam"])
             ctx.metrics["rho"] = rho
 
     # -- Optimizer protocol --------------------------------------------
     def init(self, params, batch) -> KFACState:
         self._start = None            # new run: re-arm the warmup refreshes
+        self._step = self._step_leaf = None   # and re-read the step
         if self._overlap is not None:
             self._overlap.reset()     # drop any in-flight refresh buffer
         return self.engine.init(params, batch)
@@ -890,9 +938,15 @@ class KFACPipeline:
         return state
 
     def update(self, grads, state: KFACState, params, batch, rng):
-        with self.obs.span("kfac/read_step"):
-            step = int(state.step)    # schedule off the state, not a loop var
-        self._c_read_step.inc()
+        # schedule off the state, not a loop var: the step is read once,
+        # then counted on the host for as long as the caller hands back
+        # the state this pipeline returned (each update adds 1)
+        if state.step is self._step_leaf:
+            step = self._step
+        else:
+            with self.obs.span("kfac/read_step"):
+                step = int(state.step)
+            self._c_read_step.inc()
         if self._start is None:
             self._start = step
         ctx = StepContext(step=step, warmup=step - self._start < 3,
@@ -901,7 +955,7 @@ class KFACPipeline:
         if not self.obs.enabled:
             for stage in self.stages:
                 stage.run(ctx)
-            return ctx.new_params, ctx.state, ctx.metrics
+            return self._done(ctx)
         # instrumented path: per-stage wall time (host-side, blocking on
         # the stage's outputs at span close — the jitted programs are the
         # same; only the host gains sync points) + one kfac_step event
@@ -913,12 +967,11 @@ class KFACPipeline:
                 stage.run(ctx)
             stage_s[stage.name] = sp.seconds
         self.obs.emit("kfac_step", step=step, stages=stage_s)
-        return ctx.new_params, ctx.state, ctx.metrics
+        return self._done(ctx)
 
-    def reject(self, state: KFACState) -> KFACState:
-        """Non-finite update was skipped: raise damping, drop momentum."""
-        return state.replace(lam=state.lam * 4.0,
-                             delta0=T.tree_zeros_like(state.delta0))
+    def _done(self, ctx: StepContext):
+        self._step, self._step_leaf = ctx.step + 1, ctx.state.step
+        return ctx.new_params, ctx.state, ctx.metrics
 
 
 def kfac(model=None, cfg: Optional[KFACConfig] = None, mesh=None,
@@ -940,7 +993,7 @@ def kfac(model=None, cfg: Optional[KFACConfig] = None, mesh=None,
                                                        KFACConfig(),
                                                        mesh, family)
     pipe = KFACPipeline(eng, obs=obs)
-    return Optimizer(init=pipe.init, update=pipe.update, reject=pipe.reject,
+    return Optimizer(init=pipe.init, update=pipe.update, reject=eng.reject,
                      state_shardings=eng.state_shardings,
                      poll=pipe.poll if eng.refresh_mode == "overlap" else None,
                      engine=eng, name=f"kfac_{eng.cfg.inv_mode}")

@@ -15,12 +15,21 @@ Fault tolerance:
   * SIGTERM/SIGINT preemption hook → synchronous checkpoint, clean exit;
   * non-finite guard: a NaN/Inf update is *skipped* (params untouched,
     ``opt.reject`` applied — K-FAC raises damping and clears momentum)
-    rather than poisoning the run;
+    rather than poisoning the run.  An optimizer whose metrics carry a
+    device flag ``finite`` (K-FAC) applies the skip in its own programs
+    and the trainer keeps what it returns; for the others the trainer
+    reads the check on the host;
   * elastic restart: checkpoints restore onto any mesh (see elastic.py).
+
+One step in flight: a step's scalar metrics are fetched in one transfer
+after the next step is dispatched, so ``history``, the rejected count and
+the log lines run a step behind the device; ``fit`` reads the last row
+and blocks on the params and state it returns before returning.
 """
 from __future__ import annotations
 
 import contextlib
+import dataclasses
 import signal
 import time
 from typing import Any, Dict, Optional
@@ -34,6 +43,23 @@ from repro.obs import CompileClock
 from repro.optimizers import as_optimizer
 from repro.training.checkpoint import Checkpointer
 from repro.utils import tree as T
+
+
+# the step's key in one compiled dispatch: the eager fold_in pays its
+# Python wrapper and conversions on the host at every step
+_fold_in = jax.jit(jax.random.fold_in)
+
+
+@dataclasses.dataclass
+class _Row:
+    """A dispatched step whose metrics the host has not read yet."""
+
+    step: int
+    metrics: dict                 # scalar metrics, on the device
+    finite: Any                   # guard flag: device array or host bool
+    wall_s: Optional[float] = None            # enabled only
+    syncs: float = 0.0                        # reads made dispatching it
+    compiles: int = 0
 
 
 class Trainer:
@@ -59,6 +85,8 @@ class Trainer:
             "train/host_syncs", {"site": "train/finite_check"})
         self._c_metric_syncs = self.obs.counter(
             "train/host_syncs", {"site": "train/metrics_to_host"})
+        # steps whose non-finite guard ran in the optimizer's programs
+        self._c_device_guard = self.obs.counter("train/device_guard_steps")
         self._preempted = False
         self._bundle_writer = None
         self._install_handlers()
@@ -91,6 +119,10 @@ class Trainer:
         t_start = time.time()
         fused = bool(getattr(getattr(self.opt, "engine", None),
                              "fused", False))
+        # a step's scalar metrics are read one step late, in one transfer,
+        # while the next step runs: the loop keeps one step in flight
+        pending = None
+        key = jax.random.PRNGKey(self.tc.seed)
         # compile events are counted per step only when enabled; the
         # listener is removed when the loop ends, by an exception too
         clock = (CompileClock() if self.obs.enabled
@@ -101,8 +133,7 @@ class Trainer:
                     syncs0, compiles0 = self._host_syncs(), clock.compiles
                 with self.obs.span("train/step_inputs"):
                     batch = data.batch(step)
-                    rng = jax.random.fold_in(
-                        jax.random.PRNGKey(self.tc.seed), step)
+                    rng = _fold_in(key, step)
 
                 # per-step wall time: host-side span blocking on the
                 # produced params at close (enabled only — disabled is the
@@ -112,25 +143,22 @@ class Trainer:
                                    block=lambda: new_params) as span:
                     new_params, state, metrics = self.opt.update(
                         None, state, params, batch, rng)
+                self._c_steps.inc()
 
                 # non-finite guard: skip poisoned updates, let the
-                # optimizer react (K-FAC: 4x damping + momentum reset)
-                with self.obs.span("train/finite_check"):
-                    reads = 1
-                    finite = bool(T.tree_isfinite(new_params))
+                # optimizer react (K-FAC: 4x damping + momentum reset).
+                # An optimizer whose metrics carry the device flag
+                # "finite" applied it in its own programs.
+                finite = metrics.get("finite")
+                if finite is None:
+                    finite = self._host_guard(new_params, metrics)
                     if finite:
-                        delta = metrics.get("delta_norm", 0.0)
-                        reads += isinstance(delta, jax.Array)
-                        finite = bool(np.isfinite(float(delta)))
-                self._c_finite_syncs.inc(reads)
-                self._c_steps.inc()
-                if finite:
-                    params = new_params
+                        params = new_params
+                    else:
+                        state = self.opt.reject(state)
                 else:
-                    state = self.opt.reject(state)
-                    self._c_rejected.inc()
-                    log(f"[trainer] step {step}: non-finite update SKIPPED "
-                        f"(rejected by {self.opt.name})")
+                    self._c_device_guard.inc()
+                    params = new_params
 
                 # swap hook: optimizers running asynchronous side
                 # computations (K-FAC refresh_mode="overlap") commit any
@@ -138,25 +166,15 @@ class Trainer:
                 if self.opt.poll is not None:
                     state = self.opt.poll(state)
 
-                with self.obs.span("train/metrics_to_host"):
-                    scalars = [(k, v) for k, v in metrics.items()
-                               if jnp.ndim(v) == 0]
-                    history.append({k: float(v) for k, v in scalars})
-                self._c_metric_syncs.inc(
-                    sum(isinstance(v, jax.Array) for _, v in scalars))
+                row = _Row(step, {k: v for k, v in metrics.items()
+                                  if k != "finite" and jnp.ndim(v) == 0},
+                           finite)
                 if self.obs.enabled:
-                    with self.obs.span("train/emit"):
-                        self._emit_step(step, span.seconds, history[-1],
-                                        rejected=not finite, fused=fused,
-                                        host_syncs=self._host_syncs()
-                                        - syncs0,
-                                        compiles=clock.compiles - compiles0)
-                if step % self.tc.log_every == 0:
-                    extras = " ".join(
-                        f"{k}={history[-1][k]:.2e}" for k in ("alpha", "lam")
-                        if k in history[-1])
-                    log(f"[trainer] step {step}: "
-                        f"loss={history[-1]['loss']:.4f} {extras}".rstrip())
+                    row.wall_s = span.seconds
+                    row.syncs = self._host_syncs() - syncs0
+                    row.compiles = clock.compiles - compiles0
+                self._read_row(pending, history, log, fused)
+                pending = row
 
                 if self.ckpt is not None and (
                         (step + 1) % self.tc.checkpoint_every == 0):
@@ -166,18 +184,66 @@ class Trainer:
                                    curvature_bundle=bundle_ref)
 
                 if self._preempted:
+                    self._read_row(pending, history, log, fused)
+                    pending = None
                     log(f"[trainer] preempted at step {step}; checkpointing")
                     if self.ckpt is not None:
                         self.ckpt.save(step + 1, {"params": params,
                                                   "state": state}, block=True)
                     break
+            self._read_row(pending, history, log, fused)
 
+        # the caller's clock stops once every step it counts has run
+        jax.block_until_ready((params, state))
         if self.ckpt is not None:
             self.ckpt.wait()
         if self._bundle_writer is not None:
             self._bundle_writer.wait()
         return {"params": params, "state": state, "history": history,
                 "seconds": time.time() - t_start}
+
+    def _host_guard(self, new_params, metrics) -> bool:
+        """The guard for an optimizer without a device flag: read whether
+        the new params and the update's norm are finite."""
+        with self.obs.span("train/finite_check"):
+            reads = 1
+            finite = bool(T.tree_isfinite(new_params))
+            if finite:
+                delta = metrics.get("delta_norm", 0.0)
+                reads += isinstance(delta, jax.Array)
+                finite = bool(np.isfinite(float(delta)))
+        self._c_finite_syncs.inc(reads)
+        return finite
+
+    def _read_row(self, row: Optional[_Row], history: list, log,
+                  fused: bool):
+        """Fetch a step's scalar metrics and guard flag in one transfer,
+        then record the step: history, rejected count, log lines and
+        (enabled) its ``train_step`` event."""
+        if row is None:
+            return
+        with self.obs.span("train/metrics_to_host"):
+            scalars, finite = jax.device_get((row.metrics, row.finite))
+            hist = {k: float(scalars[k]) for k in row.metrics}
+        transfers = int(any(isinstance(v, jax.Array) for v in
+                            jax.tree.leaves((row.metrics, row.finite))))
+        self._c_metric_syncs.inc(transfers)
+        history.append(hist)
+        if not finite:
+            self._c_rejected.inc()
+            log(f"[trainer] step {row.step}: non-finite update SKIPPED "
+                f"(rejected by {self.opt.name})")
+        if self.obs.enabled:
+            with self.obs.span("train/emit"):
+                self._emit_step(row.step, row.wall_s, hist,
+                                rejected=not finite, fused=fused,
+                                host_syncs=row.syncs + transfers,
+                                compiles=row.compiles)
+        if row.step % self.tc.log_every == 0:
+            extras = " ".join(f"{k}={hist[k]:.2e}" for k in ("alpha", "lam")
+                              if k in hist)
+            log(f"[trainer] step {row.step}: "
+                f"loss={hist['loss']:.4f} {extras}".rstrip())
 
     # ------------------------------------------------------------------
     def _host_syncs(self) -> float:

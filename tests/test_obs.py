@@ -246,7 +246,7 @@ def test_trainer_counts_rejected_steps():
 
 T1 = 2
 SITES = ("train/step_inputs", "kfac/read_step", "train/finite_check",
-         "train/metrics_to_host", "kfac/lambda_guard", "train/emit")
+         "train/metrics_to_host", "train/emit")
 
 
 def _kfac_fit(steps, ocfg, **kw):
@@ -264,37 +264,43 @@ def _kfac_fit(steps, ocfg, **kw):
     return out, obs, opt.update.__self__
 
 
-def _lambda_steps(steps):
-    return [k for k in range(steps) if (k + 1) % T1 == 0]
-
-
 @pytest.fixture
 def reads(monkeypatch):
     """Counts device->host conversions of jax arrays (``bool``, ``int``,
-    ``float``, ``__index__``, ``__array__``, ``item``) and calls of
+    ``float``, ``__index__``, ``__array__``, ``item``; one
+    ``jax.device_get`` of a whole tree is one read) and calls of
     ``jax.block_until_ready``."""
     from jax._src.array import ArrayImpl
     n = {"read": 0, "block": 0}
+    inside = []                  # conversions made by a device_get
 
     def counted(fn, key):
         def f(*a, **k):
-            n[key] += 1
-            return fn(*a, **k)
+            if not inside:
+                n[key] += 1
+            inside.append(fn)
+            try:
+                return fn(*a, **k)
+            finally:
+                inside.pop()
         return f
 
     for name in ("__bool__", "__int__", "__float__", "__index__",
                  "__array__", "item"):
         monkeypatch.setattr(ArrayImpl, name,
                             counted(getattr(ArrayImpl, name), "read"))
+    monkeypatch.setattr(jax, "device_get", counted(jax.device_get, "read"))
     monkeypatch.setattr(jax, "block_until_ready",
                         counted(jax.block_until_ready, "block"))
     return n
 
 
 def test_site_spans_in_profiler_trace(tmp_path):
-    """Under the profiler every site span lands in the host plane once a
-    step; ``kfac/lambda_guard`` only on T1 steps, inside its stage's
-    ``kfac/adapt_lambda`` span."""
+    """Under the profiler the site spans land in the host plane: the step
+    inputs, the metric transfer and the event once a step, the step read
+    once a fit, no finite check and no λ guard (the update programs guard
+    on the device).  Each step's transfer comes after the next step's
+    dispatch: the metrics run one step behind."""
     import glob
     steps = 5
     opts = jax.profiler.ProfileOptions()
@@ -312,19 +318,24 @@ def test_site_spans_in_profiler_trace(tmp_path):
                 for e in line.events:
                     spans.setdefault(e.name, []).append(
                         (e.start_ns, e.start_ns + e.duration_ns))
-    for site in SITES:
-        want = len(_lambda_steps(steps)) if site == "kfac/lambda_guard" \
-            else steps
-        assert len(spans.get(site, [])) == want, site
-    stage = spans["kfac/adapt_lambda"]
-    for s, e in spans["kfac/lambda_guard"]:
-        assert any(a <= s and e <= b for a, b in stage)
+    want = {"train/step_inputs": steps, "kfac/read_step": 1,
+            "train/finite_check": 0, "train/metrics_to_host": steps,
+            "kfac/lambda_guard": 0, "train/emit": steps}
+    assert {site: len(spans.get(site, [])) for site in want} == want
+    step, read = sorted(spans["train/step"]), sorted(
+        spans["train/metrics_to_host"])
+    for k in range(steps - 1):
+        # row k is read after step k + 1 is dispatched, before step k + 2
+        assert step[k + 1][1] <= read[k][0]
+        assert k + 2 == steps or read[k][1] <= step[k + 2][0]
 
 
 def test_site_spans_add_no_block_or_read(reads, monkeypatch):
-    """The new spans carry no ``block``: with them live, the enabled fit
+    """The site spans carry no ``block``: with them live, the enabled fit
     makes the same ``block_until_ready`` calls and device reads as with
-    them replaced by the no-op span."""
+    them replaced by the no-op span — one step read and one metric
+    transfer a step."""
+    steps = 6
     counts = []
     for live in (True, False):
         if not live:
@@ -333,41 +344,41 @@ def test_site_spans_add_no_block_or_read(reads, monkeypatch):
                 Obs, "span", lambda self, name, block=None:
                 NULL_SPAN if name in SITES else span(self, name, block))
         reads.update(read=0, block=0)
-        _kfac_fit(6, ObsConfig(enabled=True))
+        _kfac_fit(steps, ObsConfig(enabled=True))
         counts.append(dict(reads))
     assert counts[0] == counts[1]
-    assert counts[0]["block"] > 0 and counts[0]["read"] > 0
+    assert counts[0]["block"] > 0 and counts[0]["read"] == 1 + steps
 
 
 def test_host_syncs_count_the_schedules_reads(reads):
     """``train/host_syncs{site}`` is live with obs disabled and counts
-    each site's reads: one step read per step, the finite check's two,
-    one per scalar metric, the λ guard on T1 steps — every device read
-    the loop makes."""
+    each site's reads: one step read per fit and one transfer of a
+    step's metrics per step; no finite-check or λ-guard read (the guard
+    runs on the device, every step) — every device read the loop
+    makes."""
     steps = 6
     out, obs, _ = _kfac_fit(steps, ObsConfig())
     got = {m.labels[0][1]: m.value
            for m in obs.registry.find("train/host_syncs")}
-    assert got == {
-        "kfac/read_step": steps,
-        "train/finite_check": 2 * steps,
-        "train/metrics_to_host": sum(len(h) for h in out["history"]),
-        "kfac/lambda_guard": len(_lambda_steps(steps))}
+    assert got == {"kfac/read_step": 1, "train/finite_check": 0,
+                   "train/metrics_to_host": steps}
     assert sum(got.values()) == reads["read"]
+    assert obs.registry.counter("train/device_guard_steps").value == steps
+    assert len(out["history"]) == steps
 
 
 def test_train_step_events_carry_syncs_and_compiles(tmp_path):
     """Each ``train_step`` event carries the step's reads and backend
-    compiles: compiles on the first step, none once every program of the
-    period (refresh, λ step, γ sweep) has run."""
+    compiles: the transfer of its metrics (and, on the first step, the
+    step read); compiles on the first step, none once every program of
+    the period (refresh, λ step, γ sweep) has run."""
     steps = 9
     path = str(tmp_path / "train.jsonl")
     out, obs, _ = _kfac_fit(steps, ObsConfig(enabled=True, jsonl_path=path))
     ev = [e for e in read_jsonl(path) if e["event"] == "train_step"]
     assert [e["step"] for e in ev] == list(range(steps))
-    lam = _lambda_steps(steps)
     assert [e["host_syncs"] for e in ev] == [
-        1 + 2 + len(h) + (k in lam) for k, h in enumerate(out["history"])]
+        1 + (k == 0) for k in range(steps)]
     assert ev[0]["compiles"] > 0
     assert all(e["compiles"] == 0 for e in ev[5:])
     assert obs.registry.counter("train/compiles").value == sum(

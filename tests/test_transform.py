@@ -233,10 +233,10 @@ def test_adam_weight_decay_is_decoupled():
 
 
 def test_kfac_lambda_step_survives_nan_update():
-    """A poisoned step at a T1 boundary: the lambda stage evaluates the
-    loss at the params the trainer will keep (the old, finite ones — never
-    the NaN update), and lambda stays finite (a NaN rho leaves it as-is,
-    the trainer's reject() then raises it)."""
+    """A poisoned step at a T1 boundary: the update's guard keeps the old,
+    finite params, the lambda stage evaluates the loss there (never at the
+    NaN update), and lambda stays finite (a NaN rho leaves it as-is, the
+    guard's reject then raises it, after the rule)."""
     mlp, params, data = _problem(dims=(16, 8, 16), n=64)
     opt = optimizers.kfac(mlp, KFACConfig(lambda_init=1.0, t1=1, t3=1),
                           family="bernoulli")
@@ -252,12 +252,16 @@ def test_kfac_lambda_step_survives_nan_update():
         lambda x: jnp.full_like(x, jnp.nan), state.delta0))
     new_params, state, metrics = opt.update(None, state, params, batch,
                                             jax.random.PRNGKey(1))
-    assert not bool(T.tree_isfinite(new_params))
+    assert not bool(metrics["finite"])
+    _assert_trees_equal(new_params, params)
     # m_delta is NaN on a poisoned step, so rho is too — but lambda must
-    # not be corrupted, and reject() still escalates it cleanly
+    # not be corrupted, and the reject still escalates it cleanly
+    assert np.isnan(float(metrics["rho"]))
     assert np.isfinite(float(state.lam))
-    assert float(state.lam) == pytest.approx(lam_before)
-    assert float(opt.reject(state).lam) == pytest.approx(4 * lam_before)
+    assert float(state.lam) == pytest.approx(4 * lam_before)
+    assert float(opt.reject(state).lam) == pytest.approx(16 * lam_before)
+    assert all(float(jnp.abs(leaf).max()) == 0.0
+               for leaf in jax.tree.leaves(state.delta0))
 
 
 def test_sgd_momentum_optimizer_matches_hand_rolled_loop():
