@@ -8,7 +8,14 @@ Files are found by the names in ``BENCHMARK.json``:
 * ``bench/limits/<workload>.json``  each compared number's limit;
 * ``bench/metrics/<metric>.py``     a per-layer metric's reader;
 * ``bench/reference/<name>.py``     a configuration's plain reference;
-* ``bench/runners/<runner>.py``     the general runner a traffic names.
+* ``bench/runners/<runner>.py``     the general runner a traffic names;
+* ``bench/adapters/<family>.py``    a configuration family's model, weight
+                                    layout and training FLOPs;
+* ``bench/inputs/<kind>.py``        the batches of a traffic's data kind;
+* ``bench/tiny/<workload>.json``    a cell's cut for the CPU tests.
+
+Each lookup takes the root it searches (``bench``), so that a test can
+run a copy of the benchmark.
 """
 from __future__ import annotations
 
@@ -44,7 +51,8 @@ def load_module(kind: str, name: str, bench: str = BENCH):
 
 
 def cell(name: str, bench_json: dict, bench: str = BENCH) -> dict:
-    """Everything one workload needs, found by name."""
+    """Everything one workload needs, found by name under ``bench``,
+    which the cell keeps for the lookups that follow."""
     work = {w["name"]: w for w in bench_json["workloads"]}
     if name not in work:
         raise KeyError(f"no workload {name!r} in BENCHMARK.json")
@@ -57,7 +65,7 @@ def cell(name: str, bench_json: dict, bench: str = BENCH) -> dict:
     per_layer = [m for m in bench_json["per_layer"]
                  if name in m.get("workloads", [name])]
     return dict(workload=w, config=cfg, traffic=traffic, limits=limits,
-                end_to_end=e2e, per_layer=per_layer)
+                end_to_end=e2e, per_layer=per_layer, bench=bench)
 
 
 def peaks_for(kind: str, bench: str = BENCH) -> dict:

@@ -1,10 +1,12 @@
 """The system under test, built from a configuration and a traffic file.
 
 This is the one place the benchmark touches the program: it builds the
-model, the optimizer and the trainer the way the repository's training
-launcher does, and moves weights between the reference's layout and the
-program's.  Inputs and weights come from the benchmark (``bench/lib/
-data.py``, ``bench/reference/*.py``), never from the program.
+model (through the configuration family's adapter, ``bench/adapters/
+<family>.py``), the optimizer and the trainer the way the repository's
+training launcher does, and hands on the adapter's moves of weights
+between the reference's layout and the program's.  Inputs and weights
+come from the benchmark (``bench/inputs/``, ``bench/reference/``), never
+from the program.
 """
 from __future__ import annotations
 
@@ -12,63 +14,26 @@ import os
 import sys
 from types import SimpleNamespace
 
-from bench.lib.harness import ROOT
+from bench.lib import harness as H
+from bench.lib.harness import BENCH, ROOT
 
 SRC = os.path.join(ROOT, "src")
 if SRC not in sys.path:
     sys.path.insert(0, SRC)
 
 
-# -- weight layouts -----------------------------------------------------
-
-_ATTN = ("wq", "wk", "wv", "wo")
-_MLP = ("wg", "wu", "wd")
-
-
-def llama_to_program(p):
-    b = p["blocks"][0]
-    blk = {"ln1": b["ln1"], "ln2": b["ln2"],
-           "attn": {k: b[k] for k in _ATTN}, "mlp": {k: b[k] for k in _MLP}}
-    return {"embed": p["embed"], "final_ln": p["final_ln"], "blocks": (blk,)}
-
-
-def llama_from_program(p):
-    b = p["blocks"][0]
-    blk = {"ln1": b["ln1"], "ln2": b["ln2"], **b["attn"], **b["mlp"]}
-    return {"embed": p["embed"], "final_ln": p["final_ln"], "blocks": (blk,)}
-
-
-LAYOUT = {
-    "llama": (llama_to_program, llama_from_program),
-    "mlp_autoencoder": (lambda p: p, lambda p: p),
-}
-
-
-# -- the model ------------------------------------------------------------
-
-def _llama_model(cfg, kcfg, compute_dtype):
-    import jax.numpy as jnp
-    from repro.configs.base import ModelConfig
-    from repro.models.lm import LM
-    d, h = cfg["hidden_size"], cfg["num_attention_heads"]
-    mc = ModelConfig(
-        name=cfg["name"], family="dense", n_layers=cfg["num_hidden_layers"],
-        d_model=d, n_heads=h, n_kv_heads=cfg["num_key_value_heads"],
-        head_dim=cfg.get("head_dim", d // h), d_ff=cfg["intermediate_size"],
-        vocab_size=cfg["vocab_size"], rope_theta=cfg["rope_theta"],
-        norm_eps=cfg["rms_norm_eps"],
-        tie_embeddings=cfg["tie_word_embeddings"],
-        max_seq=cfg["max_position_embeddings"])
-    return LM(mc, kcfg, None, compute_dtype=getattr(jnp, compute_dtype))
-
-
-def _mlp_model(cfg, kcfg, compute_dtype):
-    from repro.models.mlp import MLP
-    enc = list(cfg["encoder"])
-    return MLP(enc + enc[-2::-1], nonlin=cfg["nonlin"], loss=cfg["loss"])
-
-
-MODELS = {"llama": _llama_model, "mlp_autoencoder": _mlp_model}
+def adapter(cfg: dict, bench: str = BENCH):
+    """``bench/adapters/<family>.py`` of the configuration's family: its
+    ``model(cfg, kcfg, compute_dtype)``, ``to_program``/``from_program``
+    (weights from the reference's layout to the program's and back) and
+    ``train_flops(cfg, data)``."""
+    family = cfg["family"]
+    try:
+        return H.load_module("adapters", family, bench)
+    except FileNotFoundError as e:
+        raise FileNotFoundError(
+            f"no adapter for the configuration family {family!r}: add "
+            f"bench/adapters/{family}.py ({e})") from None
 
 
 def kfac_config(opt: dict, obs_cfg):
@@ -79,9 +44,11 @@ def kfac_config(opt: dict, obs_cfg):
     return KFACConfig(obs=obs_cfg, **kw)
 
 
-def build(cfg: dict, traffic: dict, seed: int, *, trace: bool = False):
+def build(cfg: dict, traffic: dict, seed: int, *, trace: bool = False,
+          bench: str = BENCH):
     """Model, optimizer and trainer, sharing one telemetry object; spans
-    (with profiler annotations) only when ``trace``."""
+    (with profiler annotations) only when ``trace``.  The model and the
+    weight layout come from the family's adapter under ``bench``."""
     from repro import obs as obs_mod
     from repro import optimizers
     from repro.configs.base import ObsConfig, TrainConfig
@@ -91,8 +58,8 @@ def build(cfg: dict, traffic: dict, seed: int, *, trace: bool = False):
     obs = obs_mod.Obs(ocfg)
     opt_spec = traffic["optimizer"]
     kcfg = kfac_config(opt_spec, ocfg)
-    model = MODELS[cfg["family"]](cfg, kcfg,
-                                  cfg["precision"]["compute"])
+    fam = adapter(cfg, bench)
+    model = fam.model(cfg, kcfg, cfg["precision"]["compute"])
     if opt_spec["name"] != "kfac":
         raise ValueError(f"unknown optimizer {opt_spec['name']!r}")
     opt = optimizers.kfac(model, kcfg, None,
@@ -100,7 +67,7 @@ def build(cfg: dict, traffic: dict, seed: int, *, trace: bool = False):
                           obs=obs)
     tcfg = TrainConfig(steps=0, seed=seed, log_every=1 << 30, obs=ocfg)
     trainer = Trainer(model, opt, tcfg, None, None, obs=obs)
-    to_prog, from_prog = LAYOUT[cfg["family"]]
     return SimpleNamespace(model=model, opt=opt, trainer=trainer, obs=obs,
-                           to_program=to_prog, from_program=from_prog)
+                           to_program=fam.to_program,
+                           from_program=fam.from_program)
 

@@ -72,13 +72,14 @@ def is_correct(checks: dict) -> bool:
 def per_layer(cell, out, peaks, device, n_dev):
     tr = out["trace"]
     ctx = SimpleNamespace(config=cell["config"], traffic=cell["traffic"],
-                          peaks=peaks, trace=tr.raw, lo=tr.lo, hi=tr.hi,
-                          reduced=tr.reduced, n_devices=n_dev,
+                          bench=cell["bench"], peaks=peaks, trace=tr.raw,
+                          lo=tr.lo, hi=tr.hi, reduced=tr.reduced,
+                          n_devices=n_dev,
                           **{k: v for k, v in vars(tr).items()
                              if k not in ("raw", "lo", "hi", "reduced")})
     metrics = {}
     for m in cell["per_layer"]:
-        v = H.load_module("metrics", m["name"]).read(ctx)
+        v = H.load_module("metrics", m["name"], cell["bench"]).read(ctx)
         if v is not None:
             metrics[m["name"]] = {"value": float(v), "unit": m["unit"]}
     return metrics
@@ -88,7 +89,8 @@ def run_cell(cell, args, device, peaks) -> dict:
     """Everything after the look for a chip: set-up, window, reference,
     comparison; returns the result line as a dict."""
     chips = cell["workload"]["chips"]
-    runner = H.load_module("runners", cell["traffic"]["runner"])
+    runner = H.load_module("runners", cell["traffic"]["runner"],
+                           cell["bench"])
     out = runner.run(SimpleNamespace(cell=cell, seed=args.seed,
                                      seconds=args.seconds,
                                      trace=bool(args.trace),

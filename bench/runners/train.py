@@ -66,12 +66,12 @@ def compare(prog: dict, ref: dict) -> dict:
 
 def reference_readings(cfg, traffic, seed, params_ref, batches, dtype,
                        param_dtype=None, precision="highest",
-                       ns_precision=None):
+                       ns_precision=None, bench=H.BENCH):
     """Losses and per-leaf norms of the plain reference computing in
     ``dtype`` (parameters kept in ``param_dtype``, default float32) at
     matmul ``precision`` (the inverses at ``ns_precision``, where given),
     over as many steps as ``batches`` holds."""
-    ref_mod = H.load_module("reference", cfg["reference"])
+    ref_mod = H.load_module("reference", cfg["reference"], bench)
     with jax.default_matmul_precision(precision):
         model = ref_mod.Model(cfg, dtype=getattr(jnp, dtype))
         out = kfac_ref.run(model, params_ref, batches, H.seed31(seed),
@@ -107,16 +107,17 @@ def program_readings(prog, params_ref, data, steps: int) -> dict:
             "gamma": [h.get("gamma") for h in hist]}
 
 
-def setup(cfg, traffic, seed, *, trace=False):
+def setup(cfg, traffic, seed, *, trace=False, bench=H.BENCH):
     """The cell's inputs, weights and trainer, and the program's readings
     of the first steps; every program of the schedule warmed.  The
     weights are not kept: ``weights()`` makes them again from the seed, so
     that during the window only the trainer holds them."""
-    ref_mod = H.load_module("reference", cfg["reference"])
+    ref_mod = H.load_module("reference", cfg["reference"], bench)
     kw, kd = jax.random.split(H.seed_key(seed))
     weights = lambda: ref_mod.make_params(cfg, kw)
-    data = data_mod.make(traffic["data"], cfg, kd)
-    prog = program.build(cfg, traffic, H.seed31(seed), trace=trace)
+    data = data_mod.make(traffic["data"], cfg, kd, bench)
+    prog = program.build(cfg, traffic, H.seed31(seed), trace=trace,
+                         bench=bench)
     readings = program_readings(prog, weights(), data,
                                 traffic["compare_steps"])
     if traffic["warm_steps"] > traffic["compare_steps"]:
@@ -156,13 +157,13 @@ def window(s, seconds: float, trace_dir=None):
 
 def run(ctx) -> dict:
     cell, seed, seconds = ctx.cell, ctx.seed, ctx.seconds
-    cfg, traffic = cell["config"], cell["traffic"]
+    cfg, traffic, bench = cell["config"], cell["traffic"], cell["bench"]
     trace_dir = (os.path.join(H.ROOT, ".bench_trace", cell["workload"]["name"])
                  if ctx.trace else None)
     if trace_dir:
         shutil.rmtree(trace_dir, ignore_errors=True)
     with H.CompileClock() as setup_clock:
-        s = setup(cfg, traffic, seed, trace=ctx.trace)
+        s = setup(cfg, traffic, seed, trace=ctx.trace, bench=bench)
     setup_s = time.perf_counter() - ctx.t_start
     H.say(f"setup_s={setup_s:.3f} (compile {setup_clock.seconds:.1f}s, "
           f"{setup_clock.compiles} compiles, {setup_clock.traces} traces)")
@@ -191,6 +192,6 @@ def run(ctx) -> dict:
     params_ref = weights()
     batches = [data.batch(k) for k in range(traffic["compare_steps"])]
     ref = reference_readings(cfg, traffic, seed, params_ref, batches,
-                             cfg["precision"]["params"])
+                             cfg["precision"]["params"], bench=bench)
     out["checks"] = compare(readings, ref)
     return out

@@ -1,12 +1,17 @@
 """BENCHMARK.json's shape, and discovery of a cell's files by name."""
+import copy
 import json
 import os
 import re
 import shutil
+from types import SimpleNamespace
 
 import pytest
 
+from bench import run as R
 from bench.lib import harness as H
+from bench.lib import program
+from bench.tests import tiny
 
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
@@ -118,6 +123,64 @@ def test_a_new_cell_is_found_by_name_with_no_edit(tmp_path, bj):
     assert mod.read(type("C", (), {"steps": 7})) == 7.0
     after = {p: p.read_bytes() for p in before}
     assert after == before          # nothing that was there changed
+
+
+def test_a_new_family_is_added_with_no_edit(tmp_path, bj):
+    """Copy the benchmark and add a configuration of a new family as new
+    files alone (its adapter and reference reuse the llama code under the
+    new name), with a mix, limits and a tiny cut; run the tiny cell on the
+    CPU, and change no byte that was there."""
+    bench = tmp_path / "bench"
+    shutil.copytree(H.BENCH, bench,
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    before = {p: p.read_bytes() for p in bench.rglob("*") if p.is_file()}
+    fam, conf, mix, name = "newlm", "newlm-tiny", "kfac-newlm", "newlm-train"
+
+    def copy_file(kind, src, dst, ext, **changes):
+        text = (bench / kind / f"{src}.{ext}").read_text()
+        if changes:
+            text = json.dumps({**json.loads(text), **changes})
+        (bench / kind / f"{dst}.{ext}").write_text(text)
+
+    copy_file("adapters", "llama", fam, "py")
+    copy_file("reference", "llama", fam, "py")
+    copy_file("configs", "smollm-135m", conf, "json", name=conf, family=fam,
+              reference=fam)
+    copy_file("traffic", "kfac-lm-1x2048", mix, "json")
+    copy_file("limits", "smollm-kfac-train", name, "json")
+    copy_file("tiny", "smollm-kfac-train", name, "json")
+    new = copy.deepcopy(bj)
+    new["configs"].append({"name": conf, "source": "test", "reduced": [],
+                           "file": f"bench/configs/{conf}.json",
+                           "why": "test"})
+    new["workloads"].append({"name": name, "config": conf, "traffic": mix,
+                             "chips": 1, "why": "test"})
+    for m in new["end_to_end"] + new["per_layer"]:
+        if m["name"] in ("train_step_ms", "mfu.train"):
+            m["workloads"].append(name)
+    assert tiny.cuts(new, str(bench))[-1] == name
+    c = tiny.cell(name, bench_json=new, bench=str(bench))
+    assert c["config"]["family"] == fam
+    device, peaks = tiny.cpu()
+    res = R.run_cell(c, tiny.args(2_147_483_659), device, peaks)
+    assert res["correct"], res["checks"]
+    steps = res["attempted"]
+    wall = 1e-3 * res["metrics"]["train_step_ms"]["value"] * steps
+    ctx = SimpleNamespace(config=c["config"], traffic=c["traffic"],
+                          bench=c["bench"], steps=steps, wall=wall,
+                          n_devices=1, peaks=peaks)
+    mfu = H.load_module("metrics", "mfu.train", str(bench)).read(ctx)
+    assert mfu is not None and mfu > 0
+    after = {p: p.read_bytes() for p in before}
+    assert after == before          # nothing that was there changed
+
+
+def test_unknown_family_names_its_adapter_file():
+    c = tiny.cell("smollm-kfac-train")
+    cfg = dict(c["config"], family="no_such_family")
+    with pytest.raises(FileNotFoundError,
+                       match=r"bench/adapters/no_such_family\.py"):
+        program.build(cfg, c["traffic"], 0)
 
 
 def test_unknown_device_kind_is_refused():

@@ -21,6 +21,7 @@ FU = ("%vmap__.2 = f32[30,1536,1536]{2,1,0:T(8,128)} custom-call(f32[2]{0:T(128)
 def ctx(devices, host=(), **kw):
     tr = {"devices": {"/device:TPU:0": list(devices)}, "host": list(host)}
     base = dict(trace=tr, lo=0, hi=1e10, peaks=PEAKS, n_devices=1,
+                bench=H.BENCH,
                 reduced={"busy_s": 2.5, "window_s": 10.0})
     base.update(kw)
     return SimpleNamespace(**base)
@@ -92,3 +93,22 @@ def test_mfu_ae_counts_the_mirrored_autoencoder():
     dims = [784, 1000, 500, 250, 30, 250, 500, 1000, 784]
     want = 100.0 * 4 * flops.mlp_train_flops(dims, 8192) / (2.0 * 197e12)
     assert H.load_module("metrics", "mfu.ae").read(c) == pytest.approx(want)
+
+
+@pytest.mark.parametrize("config,traffic", [
+    ("smollm-135m", "kfac-lm-1x2048"), ("mnist-autoencoder", "kfac-ae-8192")])
+def test_mfu_train_is_the_family_formula_bit_for_bit(config, traffic):
+    """The adapter's ``train_flops`` gives what the reader's family
+    branches gave before the adapters: the same float, not a near one."""
+    cfg = H.load_json(H.BENCH, "configs", f"{config}.json")
+    tr = H.load_json(H.BENCH, "traffic", f"{traffic}.json")
+    data = tr["data"]
+    if "encoder" in cfg:
+        enc = list(cfg["encoder"])
+        per_step = flops.mlp_train_flops(enc + enc[-2::-1], data["batch"])
+    else:
+        per_step = flops.llama_train_flops(cfg, data["batch"], data["seq"])
+    c = ctx([], config=cfg, traffic=tr, steps=37, wall=20.013)
+    want = 100.0 * per_step * 37 / (20.013 * 1 * 197e12)
+    assert H.load_module("metrics", "mfu.train").read(c) == want
+    assert H.load_module("metrics", "mfu.ae").read(c) == want

@@ -1,32 +1,38 @@
 """Tiny cells for the CPU tests: each real cell's files, cut to a size a
-test run holds (the widths shrink here only; the chip runs the files as
-they are)."""
+test run holds by ``bench/tiny/<workload>.json`` (nested ``config`` and
+``traffic`` overrides merged into the cell; the widths shrink here only,
+the chip runs the files as they are).  A cell that ships such a file is
+in the CPU tests with no edit here."""
+import os
 from types import SimpleNamespace
 
 from bench.lib import harness as H
 
-TINY_LM = dict(hidden_size=96, intermediate_size=192, num_attention_heads=3,
-               num_key_value_heads=1, head_dim=32, num_hidden_layers=2,
-               vocab_size=512)
+
+def cuts(bench_json: dict, bench: str = H.BENCH) -> list:
+    """The manifest's workloads that have a tiny cut, in manifest order."""
+    return [w["name"] for w in bench_json["workloads"]
+            if os.path.exists(os.path.join(bench, "tiny",
+                                           f"{w['name']}.json"))]
 
 
-def _lm_train(c):
-    c["config"].update(TINY_LM)
-    c["traffic"]["data"].update(batch=2, seq=256, n_batches=4)
-    c["traffic"]["optimizer"]["kernel_backend"] = "xla"
+CUTS = cuts(H.manifest())
 
 
-def _ae_train(c):
-    c["config"].update(encoder=[64, 32, 16, 8])
-    c["traffic"]["data"].update(examples=1024, batch=256, latent=4)
+def merge(into: dict, over: dict) -> None:
+    """Merge ``over`` into ``into``: nested dicts key by key, the rest
+    replaced."""
+    for k, v in over.items():
+        if isinstance(v, dict) and isinstance(into.get(k), dict):
+            merge(into[k], v)
+        else:
+            into[k] = v
 
 
-CUTS = {"smollm-kfac-train": _lm_train, "ae-kfac-train": _ae_train}
-
-
-def cell(name: str, warm_steps: int = 1) -> dict:
-    c = H.cell(name, H.manifest())
-    CUTS[name](c)
+def cell(name: str, warm_steps: int = 1, bench_json: dict = None,
+         bench: str = H.BENCH) -> dict:
+    c = H.cell(name, bench_json or H.manifest(), bench)
+    merge(c, H.load_json(bench, "tiny", f"{name}.json"))
     c["traffic"]["warm_steps"] = warm_steps
     return c
 
