@@ -8,7 +8,7 @@ _ATTN = ("wq", "wk", "wv", "wo")
 _MLP = ("wg", "wu", "wd")
 
 
-def model(cfg, kcfg, compute_dtype):
+def model(cfg, kcfg, compute_dtype, mesh=None):
     import jax.numpy as jnp
     from repro.configs.base import ModelConfig
     from repro.models.lm import LM
@@ -21,7 +21,7 @@ def model(cfg, kcfg, compute_dtype):
         norm_eps=cfg["rms_norm_eps"],
         tie_embeddings=cfg["tie_word_embeddings"],
         max_seq=cfg["max_position_embeddings"])
-    return LM(mc, kcfg, None, compute_dtype=getattr(jnp, compute_dtype))
+    return LM(mc, kcfg, mesh, compute_dtype=getattr(jnp, compute_dtype))
 
 
 def to_program(p):

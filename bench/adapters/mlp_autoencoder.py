@@ -8,9 +8,10 @@ def _dims(cfg):
     return enc + enc[-2::-1]
 
 
-def model(cfg, kcfg, compute_dtype):
+def model(cfg, kcfg, compute_dtype, mesh=None):
     from repro.models.mlp import MLP
-    return MLP(_dims(cfg), nonlin=cfg["nonlin"], loss=cfg["loss"])
+    return MLP(_dims(cfg), nonlin=cfg["nonlin"], loss=cfg["loss"],
+               mesh=mesh)
 
 
 def to_program(p):

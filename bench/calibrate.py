@@ -18,6 +18,9 @@ For the first ``m`` of them, the same comparison of these stand-ins:
                       for float32, the precision the configuration states;
 * ``ref_ns_default``: the reference at ``highest`` but for its
                       Newton–Schulz inverses, at the default precision;
+* ``no_reduction``:   (a cell on a mesh) the program with each device's
+                      gradients and factor statistics of its own rows
+                      only (``bench/lib/faults.py``);
 * ``no_lambda_rule``, ``no_gamma_sweep``, ``no_stale_inverses``: the
                       program with a schedule fault planted (λ never
                       adapted; no γ sweep; the inverses refreshed on every
@@ -74,7 +77,7 @@ def faults_that_apply(opt: dict, steps: int) -> list:
 
 
 def _program(cell, seed, cache, fault=None):
-    from bench.lib import program
+    from bench.lib import faults, program
     from repro.configs.base import TrainConfig
     from repro.training.trainer import Trainer
     key = fault or "sound"
@@ -82,22 +85,27 @@ def _program(cell, seed, cache, fault=None):
         traffic = copy.deepcopy(cell["traffic"])
         traffic["optimizer"].update(SCHEDULE_FAULTS.get(fault, {}))
         cache[key] = program.build(cell["config"], traffic, H.seed31(seed))
+        if fault in faults.PLANTED:
+            faults.plant(fault, cache[key], cell["config"], traffic)
     prog = cache[key]
     prog.trainer = Trainer(prog.model, prog.opt,
                            TrainConfig(steps=0, seed=H.seed31(seed),
-                                       log_every=1 << 30), obs=prog.obs)
+                                       log_every=1 << 30), prog.mesh,
+                           obs=prog.obs)
     return prog
 
 
 def readings_for_seed(cell, seed, cache, stand_ins: bool):
     from bench.runners import train as T
     from bench.lib import data as data_mod
+    from bench.lib import program
     cfg, traffic = cell["config"], cell["traffic"]
     n = traffic["compare_steps"]
     ref_mod = H.load_module("reference", cfg["reference"])
     kw, kd = jax.random.split(H.seed_key(seed))
     params_ref = ref_mod.make_params(cfg, kw)
-    data = data_mod.make(traffic["data"], cfg, kd)
+    data = data_mod.make(traffic["data"], cfg, kd,
+                         mesh=program.mesh(traffic))
     batches = [data.batch(k) for k in range(n)]
     secs = {}
     t0 = time.perf_counter()
@@ -127,7 +135,10 @@ def readings_for_seed(cell, seed, cache, stand_ins: bool):
                 cfg, traffic, seed, params_ref, batches, f32,
                 ns_precision="default"),
         }
-        for fault in faults_that_apply(traffic["optimizer"], n):
+        planted = faults_that_apply(traffic["optimizer"], n)
+        if "mesh" in traffic:
+            planted.append("no_reduction")
+        for fault in planted:
             runs[fault] = lambda f=fault: T.program_readings(
                 _program(cell, seed, cache, f), params_ref, data, n)
         for name, fn in runs.items():

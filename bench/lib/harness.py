@@ -21,6 +21,7 @@ from __future__ import annotations
 
 import importlib.util
 import json
+import math
 import os
 import sys
 
@@ -60,6 +61,10 @@ def cell(name: str, bench_json: dict, bench: str = BENCH) -> dict:
     cfg = load_json(bench, "configs", f"{w['config']}.json")
     traffic = load_json(bench, "traffic", f"{w['traffic']}.json")
     limits = load_json(bench, "limits", f"{name}.json")
+    mesh = math.prod(traffic.get("mesh", {}).values())
+    if mesh != w["chips"]:
+        raise ValueError(f"workload {name!r} asks for {w['chips']} chips; "
+                         f"its traffic's mesh spans {mesh}")
     e2e = [m for m in bench_json["end_to_end"]
            if name in m.get("workloads", [name])]
     per_layer = [m for m in bench_json["per_layer"]

@@ -7,9 +7,16 @@ training launcher does, and hands on the adapter's moves of weights
 between the reference's layout and the program's.  Inputs and weights
 come from the benchmark (``bench/inputs/``, ``bench/reference/``), never
 from the program.
+
+A traffic file may name a device mesh, ``"mesh": {"data": 4, "model":
+1}``: the program is then built on it over the first devices JAX lists,
+as the launcher builds it under ``--mesh local``, and the weights handed
+to it are put on the mesh with the model's own parameter shardings.
+Without ``mesh`` nothing is placed and the program is built meshless.
 """
 from __future__ import annotations
 
+import math
 import os
 import sys
 from types import SimpleNamespace
@@ -24,9 +31,9 @@ if SRC not in sys.path:
 
 def adapter(cfg: dict, bench: str = BENCH):
     """``bench/adapters/<family>.py`` of the configuration's family: its
-    ``model(cfg, kcfg, compute_dtype)``, ``to_program``/``from_program``
-    (weights from the reference's layout to the program's and back) and
-    ``train_flops(cfg, data)``."""
+    ``model(cfg, kcfg, compute_dtype, mesh=None)``, ``to_program``/
+    ``from_program`` (weights from the reference's layout to the
+    program's and back) and ``train_flops(cfg, data)``."""
     family = cfg["family"]
     try:
         return H.load_module("adapters", family, bench)
@@ -44,11 +51,24 @@ def kfac_config(opt: dict, obs_cfg):
     return KFACConfig(obs=obs_cfg, **kw)
 
 
+def mesh(traffic: dict):
+    """The traffic's ``mesh`` over the first devices JAX lists, or None."""
+    if "mesh" not in traffic:
+        return None
+    import jax
+    from repro.launch.mesh import make_mesh
+    shape = traffic["mesh"]
+    devices = jax.devices()[:math.prod(shape.values())]
+    return make_mesh(tuple(shape.values()), tuple(shape), devices=devices)
+
+
 def build(cfg: dict, traffic: dict, seed: int, *, trace: bool = False,
           bench: str = BENCH):
     """Model, optimizer and trainer, sharing one telemetry object; spans
     (with profiler annotations) only when ``trace``.  The model and the
-    weight layout come from the family's adapter under ``bench``."""
+    weight layout come from the family's adapter under ``bench``; on a
+    mesh, ``to_program`` also puts the weights on it."""
+    import jax
     from repro import obs as obs_mod
     from repro import optimizers
     from repro.configs.base import ObsConfig, TrainConfig
@@ -59,15 +79,19 @@ def build(cfg: dict, traffic: dict, seed: int, *, trace: bool = False,
     opt_spec = traffic["optimizer"]
     kcfg = kfac_config(opt_spec, ocfg)
     fam = adapter(cfg, bench)
-    model = fam.model(cfg, kcfg, cfg["precision"]["compute"])
+    m = mesh(traffic)
+    model = fam.model(cfg, kcfg, cfg["precision"]["compute"], mesh=m)
     if opt_spec["name"] != "kfac":
         raise ValueError(f"unknown optimizer {opt_spec['name']!r}")
-    opt = optimizers.kfac(model, kcfg, None,
+    opt = optimizers.kfac(model, kcfg, m,
                           family=opt_spec.get("family", "categorical"),
                           obs=obs)
     tcfg = TrainConfig(steps=0, seed=seed, log_every=1 << 30, obs=ocfg)
-    trainer = Trainer(model, opt, tcfg, None, None, obs=obs)
+    trainer = Trainer(model, opt, tcfg, m, None, obs=obs)
+    to_program = fam.to_program
+    if m is not None:
+        to_program = lambda p: jax.device_put(fam.to_program(p),
+                                              model.param_shardings())
     return SimpleNamespace(model=model, opt=opt, trainer=trainer, obs=obs,
-                           to_program=fam.to_program,
+                           mesh=m, to_program=to_program,
                            from_program=fam.from_program)
-

@@ -13,7 +13,9 @@ preemption hook once ``--seconds`` have passed.  After the window the
 plain reference (``bench/lib/kfac_ref.py`` over the configuration's
 ``bench/reference/<name>.py``) follows the same ``compare_steps`` steps,
 schedule included, from the same weights and batches, and the numbers
-are compared.
+are compared.  On a traffic's mesh the batches stay sharded over its
+``data`` axis for the reference too, so that its plain ``jnp`` runs
+data-parallel on the cell's chips; its weights are replicated.
 """
 from __future__ import annotations
 
@@ -115,7 +117,8 @@ def setup(cfg, traffic, seed, *, trace=False, bench=H.BENCH):
     ref_mod = H.load_module("reference", cfg["reference"], bench)
     kw, kd = jax.random.split(H.seed_key(seed))
     weights = lambda: ref_mod.make_params(cfg, kw)
-    data = data_mod.make(traffic["data"], cfg, kd, bench)
+    data = data_mod.make(traffic["data"], cfg, kd, bench,
+                         program.mesh(traffic))
     prog = program.build(cfg, traffic, H.seed31(seed), trace=trace,
                          bench=bench)
     readings = program_readings(prog, weights(), data,
@@ -155,6 +158,16 @@ def window(s, seconds: float, trace_dir=None):
     return t1 - t0, steps, int(rejected), clock, host
 
 
+def memory_peak(devices):
+    """The largest ``peak_bytes_in_use`` over ``devices`` (None where the
+    backend reports none); on several, each device's goes to the log."""
+    peaks = [(d.memory_stats() or {}).get("peak_bytes_in_use")
+             for d in devices]
+    if len(peaks) > 1:
+        H.say(f"peak_bytes_in_use per device: {peaks}")
+    return None if None in peaks else max(peaks)
+
+
 def run(ctx) -> dict:
     cell, seed, seconds = ctx.cell, ctx.seed, ctx.seconds
     cfg, traffic, bench = cell["config"], cell["traffic"], cell["bench"]
@@ -172,10 +185,10 @@ def run(ctx) -> dict:
           f"window: {clock.compiles} ({clock.traces} traces); host: "
           f"{host.cpu_s:.3f} CPU s, {host.preempted} involuntary switches, "
           f"steal {host.steal_s} s")
-    dev = jax.devices()[0]
-    peak = (dev.memory_stats() or {}).get("peak_bytes_in_use")
+    devs = (list(s.prog.mesh.devices.flat) if s.prog.mesh is not None
+            else jax.devices()[:1])
     out = {"attempted": steps, "failed": rejected,
-           "memory_peak_bytes": peak,
+           "memory_peak_bytes": memory_peak(devs),
            "end_to_end": {"setup_s": setup_s,
                           traffic["step_metric"]: 1000.0 * wall / max(steps, 1)}}
     if trace_dir:
@@ -191,7 +204,9 @@ def run(ctx) -> dict:
     gc.collect()
     params_ref = weights()
     batches = [data.batch(k) for k in range(traffic["compare_steps"])]
+    t_ref = time.perf_counter()
     ref = reference_readings(cfg, traffic, seed, params_ref, batches,
                              cfg["precision"]["params"], bench=bench)
+    H.say(f"reference: {time.perf_counter() - t_ref:.1f}s")
     out["checks"] = compare(readings, ref)
     return out
